@@ -34,6 +34,7 @@ from repro.core.geometry import warp_events
 from repro.core.pipeline import make_engine_pass
 from repro.core.types import CmaxConfig
 from repro.kernels import batched_engine_pass, blur_stats, iwe_accum
+from repro.kernels.iwe_accum import CHUNK
 from repro.kernels.ref import blur_stats_ref, iwe_accum_ref
 from repro.data import events as ev_data
 from repro.roofline import (cmax_megakernel_costs, cmax_scatter_costs,
@@ -69,7 +70,7 @@ def _megakernel_suite(out: dict) -> dict:
     structural numbers: equivalence error, spill rate, and the analytic
     HBM-traffic ratios."""
     B, N = 2, 4096
-    capacity, rb, chunk = 4096, 8, 512
+    capacity, rb = 4096, 8
     batch, om_true, cam = _batch(B, N)
     cfg = CmaxConfig(camera=cam)   # paper-default stages
     hw = default_hw()
@@ -78,7 +79,7 @@ def _megakernel_suite(out: dict) -> dict:
         "hw_profile": "tpu_v5e_estimate",
         "hw": dataclasses.asdict(hw),
         "config": {"B": B, "n_events": N, "capacity": capacity, "rb": rb,
-                   "chunk": chunk,
+                   "chunk": CHUNK,
                    "camera": f"{cam.width}x{cam.height}"},
         "kernels": {},
     }
@@ -102,12 +103,12 @@ def _megakernel_suite(out: dict) -> dict:
             cnt = np.bincount(np.clip(rows[ok], 0, n_slabs * rb - 1) // rb,
                               minlength=n_slabs)
             occ = max(occ, int(cnt.max()))
-        cap_s = max(int(1.25 * occ), chunk)
-        cap = _ceil_to(max(cap_s, chunk), chunk)
+        cap_s = max(int(1.25 * occ), CHUNK)
+        cap = _ceil_to(cap_s, CHUNK)
 
         call = lambda om: batched_engine_pass(
             batch, om, cam, s, k, stage.blur_sigma, rb=rb,
-            capacity=cap_s, chunk=chunk)
+            capacity=cap_s)
         v_mk, g_mk, spilled = call(om_true)
         us = time_call(lambda: call(om_true), iters=2)
 

@@ -22,6 +22,8 @@ def main() -> None:
                     help="serving suite: write the JSONL telemetry trace "
                          "(request spans + adaptation decisions) here")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace_out:
         os.environ["BENCH_SERVING_TRACE_OUT"] = args.trace_out
 

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Tier-1 verification, as run by .github/workflows/ci.yml: install the
 # manifest dependencies, run the test suite on CPU (the Pallas kernels
-# execute with interpret=True there), then run the serving load generator
+# are interpreted there), then run the serving load generator
 # in smoke mode and gate on the recorded baseline. Falls back to
 # preinstalled deps in hermetic/offline containers; tests/conftest.py
 # shims `hypothesis` if the dev extras could not be installed.
@@ -11,7 +11,8 @@ cd "$(dirname "$0")/.."
 python -m pip install -e ".[dev]" \
     || echo "ci.sh: pip install failed (offline?); using preinstalled deps"
 
-PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -q --durations=10
+JAX_PLATFORMS=cpu PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} \
+    python -m pytest -q --durations=10
 
 # Cross-workload serving conformance + LM property suites, in full: the
 # default addopts exclude tests marked `slow` (the LM decode differential

@@ -35,7 +35,6 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .pipeline import (WindowResult, estimate_streams,
@@ -86,8 +85,8 @@ def _sharded_fn(kind: str, local, in_specs, cfg, mesh, dp, windows, omegas):
     fn = _SHARDED_FNS.get(key)
     if fn is None:
         out_specs = _leading_axis_specs(local, dp, windows, omegas)
-        fn = jax.jit(shard_map(local, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False))
+        fn = jax.jit(jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                                   out_specs=out_specs, check_vma=False))
         _SHARDED_FNS[key] = fn
     return fn
 

@@ -50,6 +50,10 @@ class StageTrace(NamedTuple):
     v_history: jax.Array    # (max_iters,) f32 padded per-iteration variance
     omega_entry: jax.Array  # (3,) hypothesis at stage entry (sort reference)
     omega_exit: jax.Array   # (3,) hypothesis at stage exit
+    spilled: jax.Array      # () int32 — taps over the megakernel's slab
+    #                         capacity, summed over the stage's passes; each
+    #                         such pass took the exact slow path (always 0
+    #                         on the other engines)
 
 
 class WindowResult(NamedTuple):
@@ -62,14 +66,15 @@ EnginePass = Callable[[EventWindow, jax.Array, jax.Array],
 
 
 def make_engine_pass(cam: Camera, stage: StageConfig, dtype=jnp.float32,
-                     engine: str = "reference", *, capacity: int = 4096,
-                     interpret: bool = True) -> EnginePass:
+                     engine: str = "reference", *,
+                     capacity: int = 4096) -> EnginePass:
     """One full engine pass at stage s: warp+vote+accumulate (IWE & dIWE),
     streaming blur statistics, Eq. 12 objective + gradient.
 
     `engine` selects the backend (types.ENGINES): "reference" is the
     pure-jnp oracle datapath; "pallas" (and, per-window, "pallas_batched")
-    routes through the fused Pallas kernel path. Returns
+    routes through the fused Pallas kernel path (the batched megakernel
+    itself is `make_batched_engine_pass`). Returns
     fn(ev, weights, omega) -> (variance, grad(3,)).
     """
     Hs, Ws = stage.grid(cam)
@@ -81,10 +86,11 @@ def make_engine_pass(cam: Camera, stage: StageConfig, dtype=jnp.float32,
 
         def kernel_engine(ev: EventWindow, weights: jax.Array,
                           omega: jax.Array):
-            v, g, _spilled = fused_engine_pass(
+            # iwe_accum adds its spilled taps through a scatter, so the
+            # pass is exact and the count is not needed here
+            v, g, _ = fused_engine_pass(
                 ev, omega, cam, stage.scale, stage.blur_taps,
-                stage.blur_sigma, weights=weights, capacity=capacity,
-                interpret=interpret)
+                stage.blur_sigma, weights=weights, capacity=capacity)
             return v, g
 
         return kernel_engine
@@ -102,39 +108,27 @@ def make_engine_pass(cam: Camera, stage: StageConfig, dtype=jnp.float32,
 
 def make_batched_engine_pass(cam: Camera, stage: StageConfig,
                              cfg: CmaxConfig):
-    """Whole-batch engine pass: fn(ev (B,N), weights (B,N), omega (B,3))
-    -> (variance (B,), grad (B,3)).
+    """Whole-batch megakernel engine pass (engine="pallas_batched"): ONE
+    pallas_call whose grid carries the batch axis (kernels/megakernel.py).
+    fn(ev (B,N), weights (B,N), omega (B,3)) -> (variance (B,),
+    grad (B,3), spilled (B,) int32)."""
+    from repro.kernels import batched_engine_pass
 
-    Under engine="pallas_batched" this is the megakernel — ONE pallas_call
-    whose grid carries the batch axis (kernels/megakernel.py); other
-    engines vmap their per-window pass (the grid, if any, never sees the
-    batch axis — the baseline the megakernel exists to beat)."""
-    if cfg.engine == "pallas_batched":
-        from repro.kernels import batched_engine_pass
+    def megakernel_engine(ev: EventWindow, weights: jax.Array,
+                          omega: jax.Array):
+        return batched_engine_pass(
+            ev, omega, cam, stage.scale, stage.blur_taps, stage.blur_sigma,
+            weights=weights, rb=cfg.engine_rb, capacity=cfg.engine_capacity,
+            dtype=cfg.dtype)
 
-        def megakernel_engine(ev: EventWindow, weights: jax.Array,
-                              omega: jax.Array):
-            v, g, _spilled = batched_engine_pass(
-                ev, omega, cam, stage.scale, stage.blur_taps,
-                stage.blur_sigma, weights=weights, rb=cfg.engine_rb,
-                capacity=cfg.engine_capacity,
-                interpret=cfg.engine_interpret, dtype=cfg.dtype)
-            return v, g
-
-        return megakernel_engine
-
-    per_window = make_engine_pass(cam, stage, cfg.dtype, engine=cfg.engine,
-                                  capacity=cfg.engine_capacity,
-                                  interpret=cfg.engine_interpret)
-    return jax.vmap(per_window, in_axes=(0, 0, 0))
+    return megakernel_engine
 
 
 def _make_engine_for(cfg: CmaxConfig, cam: Camera,
                      stage: StageConfig) -> EnginePass:
     """Per-window engine honouring the config's backend selection."""
     return make_engine_pass(cam, stage, cfg.dtype, engine=cfg.engine,
-                            capacity=cfg.engine_capacity,
-                            interpret=cfg.engine_interpret)
+                            capacity=cfg.engine_capacity)
 
 
 def _run_stage(ev: EventWindow, omega: jax.Array, opt_state: cgpr.CgprState,
@@ -213,7 +207,8 @@ def _run_stage(ev: EventWindow, omega: jax.Array, opt_state: cgpr.CgprState,
     trace = StageTrace(iters=iters, passes=iters + 1,
                        n_retained=tables.n_retained, v_final=v_fin,
                        v_entry=v_entry, v_history=hist,
-                       omega_entry=omega, omega_exit=om)
+                       omega_entry=omega, omega_exit=om,
+                       spilled=jnp.zeros((), jnp.int32))
     return om, ost, trace
 
 
@@ -244,7 +239,7 @@ def _run_stage_batched(ev: EventWindow, omega: jax.Array,
         ev.x, ev.y, ev.t, ev.p, ev.valid, omega)
     weights = tables.weights                              # (B, N)
 
-    v_entry, g_entry = engine_b(ev, weights, omega)       # (B,), (B, 3)
+    v_entry, g_entry, spill_entry = engine_b(ev, weights, omega)
 
     if cfg.adaptive:
         max_iters = stage.max_iters
@@ -263,15 +258,15 @@ def _run_stage_batched(ev: EventWindow, omega: jax.Array,
     rows = jnp.arange(B)
 
     def cond(carry):
-        _, _, _, it, done, _, _ = carry
+        _, _, _, it, done, _, _, _ = carry
         return jnp.any((~done) & (it < cap))
 
     def body(carry):
-        st, v_prev, g, it, done, hist, alpha = carry
+        st, v_prev, g, it, done, hist, alpha, spill = carry
         active = (~done) & (it < cap)                     # (B,)
         om, ost = st
         om_p, ost_p = update(om, g, ost, alpha)           # propose (all B)
-        v_p, g_p = engine_b(ev, weights, om_p)            # ONE kernel launch
+        v_p, g_p, spill_p = engine_b(ev, weights, om_p)   # ONE kernel launch
         it_c = jnp.clip(it, 0, max_iters - 1)
         hist = hist.at[rows, it_c].set(
             jnp.where(active, v_p, hist[rows, it_c]))
@@ -290,20 +285,20 @@ def _run_stage_batched(ev: EventWindow, omega: jax.Array,
         v_prev_n = jnp.where(improved, v_p, v_prev)
         # finished windows keep their carry verbatim (masked no-op)
         new = ((om_n, ost_n), v_prev_n, g_n, it + 1,
-               done_ok | done_stuck, hist, alpha_n)
+               done_ok | done_stuck, hist, alpha_n, spill + spill_p)
         return _masked_select(active, new, carry)
 
     hist0 = jnp.full((B, max_iters), jnp.nan, dtype=v_entry.dtype)
-    (om, ost), v_fin, _, iters, _, hist, _ = jax.lax.while_loop(
+    (om, ost), v_fin, _, iters, _, hist, _, spilled = jax.lax.while_loop(
         cond, body,
         ((omega, opt_state), v_entry, g_entry,
          jnp.zeros((B,), jnp.int32), jnp.zeros((B,), bool), hist0,
-         jnp.full((B,), alpha0, cfg.dtype)))
+         jnp.full((B,), alpha0, cfg.dtype), spill_entry))
 
     trace = StageTrace(iters=iters, passes=iters + 1,
                        n_retained=tables.n_retained, v_final=v_fin,
                        v_entry=v_entry, v_history=hist,
-                       omega_entry=omega, omega_exit=om)
+                       omega_entry=omega, omega_exit=om, spilled=spilled)
     return om, ost, trace
 
 

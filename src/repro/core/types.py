@@ -117,8 +117,8 @@ class CmaxConfig:
     The remaining engine_* fields are kernel knobs: `engine_capacity` is
     the per-(window, slab) tap budget of the batched megakernel (and the
     per-tile budget of the per-window kernels), `engine_rb` the row-slab
-    height, `engine_interpret` runs the kernels in interpret mode (the
-    only option on CPU; set False on real TPUs).
+    height. Whether the kernels compile or are interpreted follows the
+    backend (kernels/backend.py): a TPU compiles, the CPU interprets.
     """
 
     camera: Camera = Camera()
@@ -138,7 +138,6 @@ class CmaxConfig:
     engine: str = "reference"                     # one of ENGINES
     engine_capacity: int = 4096                   # per-(window, slab) taps
     engine_rb: int = 8                            # megakernel row-slab height
-    engine_interpret: bool = True                 # Pallas interpret mode
 
     def __post_init__(self):
         if self.engine not in ENGINES:

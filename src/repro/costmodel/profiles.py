@@ -7,8 +7,7 @@ are accepted, resolved by extension:
     `characterization.py` tables: a `# section.name` row opens a section,
     following `key,value` rows populate it, blank rows are ignored.
   * `.toml` — the same sections as TOML tables (`[pipeline]`,
-    `[memory.iwe]`, ...). Parsed with `tomllib` (3.11+) or `tomli` when
-    available; loading a TOML profile without either raises ProfileError.
+    `[memory.iwe]`, ...), parsed with the standard library's `tomllib`.
 
 Every profile must carry exactly the sections/keys of `SCHEMA` (plus the
 free-form `meta` extras listed in `_META_OPTIONAL`): a missing section or
@@ -24,6 +23,7 @@ from __future__ import annotations
 import csv
 import json
 import os
+import tomllib
 from typing import Dict, List
 
 PROFILE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -163,17 +163,8 @@ def _parse_csv(path: str) -> Dict[str, Dict[str, object]]:
 
 
 def _parse_toml(path: str) -> Dict[str, Dict[str, object]]:
-    try:
-        import tomllib as toml_mod
-    except ImportError:
-        try:
-            import tomli as toml_mod
-        except ImportError:
-            raise ProfileError(
-                f"{os.path.basename(path)}: TOML profiles need tomllib "
-                "(py311+) or tomli; re-encode the profile as sectioned CSV")
     with open(path, "rb") as f:
-        data = toml_mod.load(f)
+        data = tomllib.load(f)
     sections: Dict[str, Dict[str, object]] = {}
     for sec, body in data.items():
         if not isinstance(body, dict):

@@ -9,110 +9,150 @@ back to memory.
 TPU realization: a row-block-streaming kernel with a *line buffer in VMEM
 scratch*, the direct analogue of the hardware's 36 line buffers:
 
-  * grid step i loads RB rows of the (4, Hp, Wp) channel stack,
-  * horizontal 1-D FIR across the padded W axis (vector ops),
+  * grid step i loads RB image rows; each row is a (4, Wp) channel-major
+    tile (channels on sublanes, the padded W axis on lanes),
+  * horizontal 1-D FIR: lane rotations (`pltpu.roll`) of each row,
   * the last (K-1) horizontally-blurred rows of the previous block are
-    carried in VMEM scratch; concatenated with the current block they give
-    a valid vertical window for RB output rows (lagged by K//2 rows),
-  * each emitted blurred row is immediately reduced into the stats
-    accumulator (VMEM scratch), masked to the valid HxW region,
-  * the final grid step writes the (8,) stats vector — the only HBM output.
+    carried in VMEM scratch; with the current block they give a valid
+    vertical window for RB output rows (lagged by K//2 rows),
+  * each emitted blurred row is masked to the valid HxW region and added
+    into two (4, Wp) running-sum tiles (VMEM scratch): the channels
+    [I, Dx, Dy, Dz] and their products with I [I^2, I*Dx, I*Dy, I*Dz],
+  * the final grid step reduces those tiles over the lanes and writes the
+    eight sums — the only HBM output.
 
-HBM traffic: read the channel stack once, write 8 scalars. The paper's
-claim "removes an entire writeback/readback pass" is structural here.
+The horizontal FIR needs no edge mask: rows are zero beyond column W and
+Wp >= W + K//2, so every rotated-in value at an edge is a zero, and the
+taps are symmetric, so the result does not depend on the rotation's
+direction.
+
+HBM traffic: read the channel stack once, write the stats block. The
+paper's claim "removes an entire writeback/readback pass" is structural
+here. `blur_rows_into_stats` and `emit_stats` are shared with the
+megakernel (kernels/megakernel.py), which feeds them rows it voted itself.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+
+
+def blur_rows_into_stats(rows: Sequence[jax.Array], i, taps_ref, lb_ref,
+                         acc_ref, *, k: int, H: int, W: int) -> None:
+    """Blur RB channel rows (each (4, Wp) f32) and fold them into the
+    running sums.
+
+    `i` is the block index: block i holds image rows [i*RB, (i+1)*RB) and
+    emits blurred rows [i*RB - K//2, (i+1)*RB - K//2). taps_ref: (K,) FIR
+    in SMEM; lb_ref: (K-1, 4, Wp) line buffer; acc_ref: (2, 4, Wp) running
+    sums. Both scratches must be zeroed before block 0."""
+    rb = len(rows)
+    half = k // 2
+    Wp = rows[0].shape[-1]
+    taps = [taps_ref[j] for j in range(k)]
+
+    def hblur(row):
+        out = taps[half] * row
+        for j in range(k):
+            if j != half:
+                # rolled[x] = row[x + j - half]
+                shift = (half - j) % Wp
+                out = out + taps[j] * pltpu.roll(row, shift, 1)
+        return out
+
+    win = [lb_ref[j] for j in range(k - 1)] + [hblur(r) for r in rows]
+    for j in range(k - 1):
+        lb_ref[j] = win[rb + j]
+
+    col_ok = jax.lax.broadcasted_iota(jnp.int32, (1, Wp), 1) < W
+    s1 = acc_ref[0]
+    s2 = acc_ref[1]
+    for r in range(rb):
+        vb = taps[0] * win[r]
+        for j in range(1, k):
+            vb = vb + taps[j] * win[r + j]
+        row = i * rb - half + r
+        ok = col_ok & (row >= 0) & (row < H)
+        m = jnp.where(ok, vb, 0.0)                 # [I, Dx, Dy, Dz]
+        s1 = s1 + m
+        s2 = s2 + m * m[0:1]                       # [I^2, I*Dx, I*Dy, I*Dz]
+    acc_ref[0] = s1
+    acc_ref[1] = s2
+
+
+def emit_stats(acc_ref, out_ref) -> None:
+    """Reduce the running-sum tiles over the lanes into the (8, 128) stats
+    block: row c holds sum(channel c) for c < 4 and sum(I * channel c) at
+    row 4 + c, broadcast along the lanes (`stats_from_block` decodes it)."""
+    lanes = out_ref.shape[-1]
+    for a in range(2):
+        tot = jnp.sum(acc_ref[a], axis=1, keepdims=True)    # (4, 1)
+        out_ref[4 * a:4 * a + 4, :] = jnp.broadcast_to(tot, (4, lanes))
+
+
+def stats_from_block(block: jax.Array) -> jax.Array:
+    """(..., 8, 128) stats blocks -> (..., 8) Eq. 12 sums
+    [S1, S2, Gx, Gy, Gz, Tx, Ty, Tz]."""
+    r = block[..., 0]
+    return jnp.stack([r[..., 0], r[..., 4], r[..., 5], r[..., 6],
+                      r[..., 7], r[..., 1], r[..., 2], r[..., 3]], axis=-1)
+
 
 def _kernel(ch_ref, taps_ref, out_ref, lb_ref, acc_ref, *,
-            rb: int, k: int, H: int, W: int, Wp: int, n_blocks: int):
+            rb: int, k: int, H: int, W: int, n_blocks: int):
     """One grid step: process RB rows of all 4 channels."""
     i = pl.program_id(0)
-    half = k // 2
 
     @pl.when(i == 0)
     def _init():
         lb_ref[...] = jnp.zeros_like(lb_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    block = ch_ref[...]                       # (4, RB, Wp)
-    taps = taps_ref[...]                      # (k,) padded f32
-
-    # ---- horizontal FIR (zero 'same' padding via the Wp pad region) ----
-    # hrow[x] = sum_j taps[j] * row[x + j - half], zeros outside [0, W)
-    hb = jnp.zeros_like(block)
-    for j in range(k):
-        shift = j - half
-        # shift the W axis by `shift` with zero fill
-        rolled = jnp.roll(block, -shift, axis=-1)
-        col = jax.lax.broadcasted_iota(jnp.int32, block.shape, 2)
-        src = col + shift
-        valid = (src >= 0) & (src < W)
-        hb = hb + taps[j] * jnp.where(valid, rolled, 0.0)
-
-    # ---- vertical FIR through the line buffer ----
-    lb = lb_ref[...]                          # (4, k-1, Wp): previous rows
-    win = jnp.concatenate([lb, hb], axis=1)   # (4, k-1+RB, Wp)
-    # output row j of this step corresponds to image row i*RB - half + j
-    vb = jnp.zeros((4, rb, win.shape[-1]), jnp.float32)
-    for j in range(k):
-        vb = vb + taps[j] * jax.lax.dynamic_slice_in_dim(win, j, rb, axis=1)
-    lb_ref[...] = win[:, rb:rb + k - 1, :]    # carry last k-1 rows
-
-    # ---- masked on-the-fly statistics ----
-    row0 = i * rb - half
-    row_ids = row0 + jax.lax.broadcasted_iota(jnp.int32, (rb, Wp), 0)
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (rb, Wp), 1)
-    mask = ((row_ids >= 0) & (row_ids < H) & (col_ids < W)).astype(
-        jnp.float32)
-    I = vb[0] * mask
-    Dx = vb[1] * mask
-    Dy = vb[2] * mask
-    Dz = vb[3] * mask
-    part = jnp.stack([
-        jnp.sum(I), jnp.sum(I * I),
-        jnp.sum(I * Dx), jnp.sum(I * Dy), jnp.sum(I * Dz),
-        jnp.sum(Dx), jnp.sum(Dy), jnp.sum(Dz),
-    ])
-    acc_ref[...] = acc_ref[...] + part
+    rows = [ch_ref[r].astype(jnp.float32) for r in range(rb)]
+    blur_rows_into_stats(rows, i, taps_ref, lb_ref, acc_ref, k=k, H=H, W=W)
 
     @pl.when(i == n_blocks - 1)
     def _emit():
-        out_ref[...] = acc_ref[...]
+        emit_stats(acc_ref, out_ref)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("rb", "k", "H", "W", "interpret"))
 def blur_stats_streaming(channels: jax.Array, taps: jax.Array, *, rb: int,
                          k: int, H: int, W: int,
-                         interpret: bool = True) -> jax.Array:
-    """channels: (4, Hp, Wp) zero-padded stack (Hp = n_blocks*RB >= H+K//2,
-    Wp >= W + K//2, lane-aligned); taps: (k,) FIR. Returns (8,) f32 stats."""
-    _, Hp, Wp = channels.shape
-    assert Hp % rb == 0
+                         interpret: Optional[bool] = None) -> jax.Array:
+    """channels: (Hp, 4, Wp) row-major zero-padded stack (Hp = n_blocks*RB
+    >= H + K//2, Wp >= W + K//2 and a multiple of 128); taps: (k,) FIR.
+    Returns the (8, 128) stats block (`stats_from_block` decodes it)."""
+    Hp, _, Wp = channels.shape
+    if Hp % rb or Hp < H + k // 2:
+        raise ValueError(f"pad rows to a multiple of rb={rb} covering "
+                         f"H + k//2 = {H + k // 2} (got {Hp})")
+    if Wp % 128 or Wp < W + k // 2:
+        raise ValueError(f"pad columns to a multiple of 128 covering "
+                         f"W + k//2 = {W + k // 2} (got {Wp})")
     n_blocks = Hp // rb
-    assert n_blocks * rb >= H + k // 2, "pad rows so the tail flushes"
-    kern = functools.partial(_kernel, rb=rb, k=k, H=H, W=W, Wp=Wp,
+    kern = functools.partial(_kernel, rb=rb, k=k, H=H, W=W,
                              n_blocks=n_blocks)
     return pl.pallas_call(
         kern,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((4, rb, Wp), lambda i: (0, i, 0)),
-            pl.BlockSpec((k,), lambda i: (0,)),
+            pl.BlockSpec((rb, 4, Wp), lambda i: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((8,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((8,), jnp.float32),
+        out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((4, k - 1, Wp), jnp.float32),
-            pltpu.VMEM((8,), jnp.float32),
+            pltpu.VMEM((k - 1, 4, Wp), jnp.float32),     # line buffer
+            pltpu.VMEM((2, 4, Wp), jnp.float32),         # running sums
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(channels, taps)

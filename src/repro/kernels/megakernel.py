@@ -1,43 +1,45 @@
 """Batched CMAX megakernel: the full engine pass as ONE pallas_call.
 
-This is the §2 playbook taken to its limit (ROADMAP open item 2): where
-`iwe_accum` + `blur_stats` split the engine pass into two kernels joined
-by an HBM round trip of the (4, H_s, W_s) channel stack, and the batched
-serving path was `vmap` over per-window kernels (the grid never saw the
-batch axis), this kernel fuses
+This is the §2 playbook taken to its limit: where `iwe_accum` +
+`blur_stats` split the engine pass into two kernels joined by an HBM round
+trip of the (4, H_s, W_s) channel stack, and the batched serving path was
+`vmap` over per-window kernels (the grid never saw the batch axis), this
+kernel fuses
 
-    warp (Alg. 2)  ->  bilinear one-hot vote (MXU dot)  ->  row-slab
-    accumulation in VMEM  ->  streaming separable blur through a VMEM
-    line buffer  ->  Eq. 12 eight-sum statistics
+    bilinear one-hot vote (MXU dot)  ->  row-slab accumulation in VMEM
+    ->  streaming separable blur through a VMEM line buffer
+    ->  Eq. 12 eight-sum statistics
 
 into a single kernel whose grid is **(batch, slab)**: a B-window batch is
 one kernel launch, the per-(b, slab) accumulator lives in VMEM across all
-fused stages, and the only HBM write per window is its (8,) stats vector.
+fused stages, and the only HBM write per window is its stats block.
 
   FPGA mechanism                      batched-grid realization here
   ------------------------------      -------------------------------------
   pixel-grouped sorting (Alg. 3)      taps binned by (window, row-slab) in
                                       the jnp prologue; grid step (b, i)
                                       streams only its slab's taps
-  shared warp front-end (Alg. 2)      the warp is recomputed per tap slot
-                                      INSIDE the kernel (VPU element-wise)
-                                      so warped coordinates never touch HBM
+  shared warp front-end (Alg. 2)      the prologue warps each event once
+                                      per pass and both bins the taps and
+                                      forms their deltas from that one
+                                      warp, so a tap is voted into exactly
+                                      the slab it was binned to
   conflict-free banked voting         one-hot x delta MXU contraction — no
                                       RMW hazard exists at all
   local accumulation + pending merge  the slab accumulates in VMEM and is
                                       consumed in place by the blur; the
                                       full channel stack NEVER reaches HBM
-  36 line buffers (blur)              (4, K-1, Wp) VMEM scratch carried
+  36 line buffers (blur)              (K-1, 4, Wp) VMEM scratch carried
                                       across the slab axis of the grid
-  on-the-fly statistics (Eq. 12)      (8,) VMEM accumulator, flushed to HBM
-                                      once per window at the last slab
+  on-the-fly statistics (Eq. 12)      (2, 4, Wp) VMEM running sums, reduced
+                                      and flushed once per window
   outlier FIFO (fixed depth)          fixed per-(b, slab) tap capacity;
                                       spills are counted per window
 
 The tile of the (batch, tile) grid is a full-width row slab (RB x Wp):
 that is the unique tiling on which the vote's spatial partition and the
-blur's sequential line-buffer streaming coincide, so all five stages can
-share one accumulator residency.
+blur's sequential line-buffer streaming coincide, so all stages can share
+one accumulator residency.
 
 Grid iteration order matters: the slab axis is the fastest-varying grid
 dimension, so for each window b the slabs run top-to-bottom and the line
@@ -45,171 +47,85 @@ buffer / stats scratch carry exactly that window's state (both are reset
 at slab 0). TPU grids are sequential per core, which makes this carry
 legal — the same property `blur_stats` already exploits.
 
-Numerics: each (b, i) step depends only on window b's binned taps and
-omega[b], so a window's result is bit-identical whatever batch it rides
-in (B=1 == any slot of any B) — the invariant the serving layer's
-out-of-order refill relies on, pinned by tests/test_megakernel.py.
+Numerics: each (b, i) step reads only window b's records and runs the
+same instructions for every b, so the compiled kernel gives a window the
+same stats whatever batch it rides in. What the serving layer relies on
+is the weaker contract the whole pipeline keeps: at a FIXED batch size a
+slot's result depends only on that slot's inputs (tests/test_megakernel.py
+pins both).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import resolve_interpret
+from .blur_stats import blur_rows_into_stats, emit_stats
+from .iwe_accum import CHUNK, onehot_vote
 
-def _kernel(x_ref, y_ref, dt_ref, pw_ref, tap_ref, om_ref, taps_ref,
-            out_ref, lb_ref, acc_ref, *, cap: int, chunk: int, rb: int,
-            k: int, H: int, W: int, Wp: int, n_slabs: int, scale: float,
-            fx: float, fy: float, cx: float, cy: float, dtype):
-    """One grid step: the full fused engine pass for slab i of window b."""
+
+def _kernel(pix_ref, delta_ref, taps_ref, out_ref, lb_ref, acc_ref, *,
+            rb: int, k: int, H: int, W: int, Wp: int, n_slabs: int):
+    """One grid step: the fused vote/blur/stats pass for slab i of
+    window b."""
     i = pl.program_id(1)
-    half = k // 2
 
     @pl.when(i == 0)
     def _reset():
         lb_ref[...] = jnp.zeros_like(lb_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0, 0]                                  # (CAP,)
-    y = y_ref[0, 0]
-    dt = dt_ref[0, 0]
-    pw = pw_ref[0, 0]
-    tap = tap_ref[0, 0]                              # int32, -1 = padded
-    om = om_ref[0]                                   # (3,)
-    taps = taps_ref[...]                             # (k,) blur FIR
-
-    # ---- warp front-end (Alg. 2), recomputed per tap slot on the VPU ----
-    # Identical op sequence to geometry.warp_events so the in-kernel floor
-    # agrees bit-for-bit with the prologue's slab binning.
-    xn = (x - cx) / fx
-    yn = (y - cy) / fy
-    Bq = 1.0 + xn * xn
-    Dq = 1.0 + yn * yn
-    XY = xn * yn
-    wx, wy, wz = om[0], om[1], om[2]
-    u = fx * (XY * wx - Bq * wy + yn * wz)
-    v = fy * (Dq * wx - XY * wy - xn * wz)
-    xw = scale * (x - dt * u)
-    yw = scale * (y - dt * v)
-    x0 = jnp.floor(xw).astype(jnp.int32)
-    y0 = jnp.floor(yw).astype(jnp.int32)
-    ax = xw - x0
-    ay = yw - y0
-    sdt = scale * dt
-    rx0, rx1, rx2 = sdt * fx * XY, -(sdt * fx * Bq), sdt * fx * yn
-    ry0, ry1, ry2 = sdt * fy * Dq, -(sdt * fy * XY), -(sdt * fy * xn)
-
-    # ---- bilinear vote deltas from the tap code (iwe.TAP_OFFSETS order:
-    # tap = 2*dy + dx) ----
-    dy_t = tap // 2
-    dx_t = tap % 2
-    is_dx = dx_t == 1
-    is_dy = dy_t == 1
-    wt = jnp.where(is_dx, ax, 1.0 - ax) * jnp.where(is_dy, ay, 1.0 - ay)
-    cxc = jnp.where(is_dy, ay, 1.0 - ay) * jnp.where(is_dx, -1.0, 1.0)
-    cyc = jnp.where(is_dx, ax, 1.0 - ax) * jnp.where(is_dy, -1.0, 1.0)
-    d_iwe = pw * wt
-    d_x = pw * (cxc * rx0 + cyc * ry0)
-    d_y = pw * (cxc * rx1 + cyc * ry1)
-    d_z = pw * (cxc * rx2 + cyc * ry2)
-    delta = jnp.stack([d_iwe, d_x, d_y, d_z], axis=-1).astype(dtype)
-
-    # slab-local pixel id; padded slots (tap < 0) vanish in the one-hot
-    lr = y0 + dy_t - i * rb
-    lc = x0 + dx_t
-    pix = jnp.where(tap >= 0, lr * Wp + lc, -1)
-
-    # ---- one-hot vote -> slab accumulation (chunked MXU contractions,
-    # accumulator resident in VMEM/VREGs) ----
-    p_slab = rb * Wp
-    slab = jnp.zeros((p_slab, 4), jnp.float32)
-    for c in range(cap // chunk):
-        pix_c = jax.lax.dynamic_slice_in_dim(pix, c * chunk, chunk)
-        del_c = jax.lax.dynamic_slice_in_dim(delta, c * chunk, chunk)
-        iota_p = jax.lax.broadcasted_iota(jnp.int32, (chunk, p_slab), 1)
-        onehot = (pix_c[:, None] == iota_p).astype(dtype)
-        slab = slab + jax.lax.dot_general(
-            onehot, del_c,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    ch = slab.reshape(rb, Wp, 4).transpose(2, 0, 1)   # (4, RB, Wp)
-
-    # ---- horizontal FIR (zero 'same' padding via the Wp pad region) ----
-    hb = jnp.zeros_like(ch)
-    for j in range(k):
-        shift = j - half
-        rolled = jnp.roll(ch, -shift, axis=-1)
-        col = jax.lax.broadcasted_iota(jnp.int32, ch.shape, 2)
-        src = col + shift
-        valid = (src >= 0) & (src < W)
-        hb = hb + taps[j] * jnp.where(valid, rolled, 0.0)
-
-    # ---- vertical FIR through the per-window line buffer ----
-    lb = lb_ref[...]                                  # (4, k-1, Wp)
-    win = jnp.concatenate([lb, hb], axis=1)
-    vb = jnp.zeros((4, rb, Wp), jnp.float32)
-    for j in range(k):
-        vb = vb + taps[j] * jax.lax.dynamic_slice_in_dim(win, j, rb, axis=1)
-    lb_ref[...] = win[:, rb:rb + k - 1, :]
-
-    # ---- masked on-the-fly Eq. 12 statistics ----
-    row0 = i * rb - half
-    row_ids = row0 + jax.lax.broadcasted_iota(jnp.int32, (rb, Wp), 0)
-    col_ids = jax.lax.broadcasted_iota(jnp.int32, (rb, Wp), 1)
-    mask = ((row_ids >= 0) & (row_ids < H) & (col_ids < W)).astype(
-        jnp.float32)
-    I = vb[0] * mask
-    Dx = vb[1] * mask
-    Dy = vb[2] * mask
-    Dz = vb[3] * mask
-    part = jnp.stack([
-        jnp.sum(I), jnp.sum(I * I),
-        jnp.sum(I * Dx), jnp.sum(I * Dy), jnp.sum(I * Dz),
-        jnp.sum(Dx), jnp.sum(Dy), jnp.sum(Dz),
-    ])
-    acc_ref[...] = acc_ref[...] + part
+    # one-hot vote -> (4, RB*Wp) slab accumulator, then split into rows
+    slab = onehot_vote(pix_ref, delta_ref, rb * Wp)
+    rows = [slab[:, r * Wp:(r + 1) * Wp] for r in range(rb)]
+    blur_rows_into_stats(rows, i, taps_ref, lb_ref, acc_ref, k=k, H=H, W=W)
 
     @pl.when(i == n_slabs - 1)
     def _emit():
-        out_ref[0] = acc_ref[...]
+        emit_stats(acc_ref, out_ref)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("cap", "chunk", "rb", "k", "H", "W", "Wp", "n_slabs",
-                     "scale", "fx", "fy", "cx", "cy", "dtype", "interpret"))
-def megakernel_stats(x, y, dt, pw, tap, omega, fir_taps, *, cap: int,
-                     chunk: int, rb: int, k: int, H: int, W: int, Wp: int,
-                     n_slabs: int, scale: float, fx: float, fy: float,
-                     cx: float, cy: float, dtype=jnp.float32,
-                     interpret: bool = True) -> jax.Array:
-    """pallas_call wrapper: slab-binned tap records (B, NS, CAP) + per-window
-    hypotheses (B, 3) -> (B, 8) Eq. 12 stats. ONE launch for the whole
-    batch: grid = (B, NS) with the slab axis fastest, so per-window scratch
-    (line buffer + stats accumulator) is carried across each window's slabs
-    and flushed to HBM exactly once per window."""
-    B = omega.shape[0]
+    static_argnames=("rb", "k", "H", "W", "Wp", "interpret"))
+def megakernel_stats(pix, deltas, fir_taps, *, rb: int, k: int,
+                     H: int, W: int, Wp: int,
+                     interpret: Optional[bool] = None) -> jax.Array:
+    """pallas_call wrapper: slab-binned tap records -> (B, 8, 128) stats
+    blocks (`blur_stats.stats_from_block` decodes them).
+
+    pix: (B, NS, 1, CAP) int32 slab-local pixel ids (row * Wp + col,
+    -1 = padded slot); deltas: (B, NS, 4, CAP) channel deltas; fir_taps:
+    (K,) blur FIR. ONE launch for the whole batch: grid = (B, NS) with the
+    slab axis fastest, so per-window scratch (line buffer + running sums)
+    is carried across each window's slabs and flushed to HBM exactly once
+    per window. CAP must be a multiple of `iwe_accum.CHUNK`."""
+    B, n_slabs, _, cap = pix.shape
+    if cap % CHUNK:
+        raise ValueError(f"capacity {cap} is not a multiple of {CHUNK}")
+    if Wp % 128 or Wp < W + k // 2 or n_slabs * rb < H + k // 2:
+        raise ValueError("slabs must cover H + k//2 rows and Wp >= W + k//2 "
+                         "lane-aligned columns")
     kern = functools.partial(
-        _kernel, cap=cap, chunk=chunk, rb=rb, k=k, H=H, W=W, Wp=Wp,
-        n_slabs=n_slabs, scale=scale, fx=fx, fy=fy, cx=cx, cy=cy,
-        dtype=dtype)
-    rec = pl.BlockSpec((1, 1, cap), lambda b, i: (b, i, 0))
+        _kernel, rb=rb, k=k, H=H, W=W, Wp=Wp, n_slabs=n_slabs)
     return pl.pallas_call(
         kern,
         grid=(B, n_slabs),
         in_specs=[
-            rec, rec, rec, rec, rec,                     # x, y, dt, pw, tap
-            pl.BlockSpec((1, 3), lambda b, i: (b, 0)),   # omega
-            pl.BlockSpec((k,), lambda b, i: (0,)),       # blur taps
+            pl.BlockSpec((None, None, 1, cap), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec((None, None, 4, cap), lambda b, i: (b, i, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # blur taps
         ],
-        out_specs=pl.BlockSpec((1, 8), lambda b, i: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, 8), jnp.float32),
+        out_specs=pl.BlockSpec((None, 8, 128), lambda b, i: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 8, 128), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((4, k - 1, Wp), jnp.float32),     # line buffer
-            pltpu.VMEM((8,), jnp.float32),               # stats accumulator
+            pltpu.VMEM((k - 1, 4, Wp), jnp.float32),     # line buffer
+            pltpu.VMEM((2, 4, Wp), jnp.float32),         # running sums
         ],
-        interpret=interpret,
-    )(x, y, dt, pw, tap, omega, fir_taps)
+        interpret=resolve_interpret(interpret),
+    )(pix, deltas, fir_taps)

@@ -33,3 +33,15 @@ def blur_stats_ref(channels: jax.Array, num_taps: int,
     the blurred images (which the kernel never does)."""
     taps = gaussian_taps(num_taps, sigma, jnp.float32)
     return streaming_stats(channels.astype(jnp.float32), taps)
+
+
+def batched_engine_stats_ref(ev: EventWindow, omega: jax.Array, cam: Camera,
+                             scale: float, num_taps: int, sigma: float,
+                             weights: jax.Array) -> jax.Array:
+    """Oracle for kernels.batched_engine_stats, and its slow path: the
+    (B, 8) Eq. 12 sums of a (B, N) batch through the reference scatter-add
+    datapath, window by window."""
+    return jax.vmap(lambda x, y, t, p, v, om, wt: blur_stats_ref(
+        iwe_accum_ref(EventWindow(x, y, t, p, v), om, cam, scale,
+                      weights=wt), num_taps, sigma))(
+        ev.x, ev.y, ev.t, ev.p, ev.valid, omega, weights)

@@ -238,8 +238,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
              "temp_size_in_bytes", "generated_code_size_in_bytes",
              "alias_size_in_bytes")}
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
         rec["cost"] = {k: float(v) for k, v in cost.items()
                        if isinstance(v, (int, float, np.floating))
                        and k in ("flops", "bytes accessed",

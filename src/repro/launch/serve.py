@@ -304,6 +304,9 @@ class _ServingMetrics:
                             "real payload slots dispatched")
         self.fill_slots = c("repro_serving_fill_slots_total",
                             "leader-replicated batch fill slots")
+        self.spilled_taps = c("repro_serving_spilled_taps_total",
+                              "work over the fast path's capacity, "
+                              "recomputed on the exact slow path")
         shed = c("repro_serving_shed_total",
                  "requests dropped unserved, by reason",
                  labels=("reason",))
@@ -654,6 +657,8 @@ class AsyncBatchedEstimationService:
         now = self.clock.now()
         track_gain = any(q.budgeted for q in self.qos_classes.values())
         slot = self.workload.harvest(res, track_gain)
+        self._m.spilled_taps.inc(
+            self.workload.spilled_taps(res, len(fb.requests)))
         meta = self.workload.decision_meta(res) \
             if self._decisions.enabled else None
         for i, r in enumerate(fb.requests):
@@ -915,6 +920,7 @@ class BatchedEstimationService:
         self._m.execute.observe(t_done - t_dispatch)
 
         slot = self.workload.harvest(res, False)
+        self._m.spilled_taps.inc(self.workload.spilled_taps(res, len(batch)))
         out = []
         for i, req in enumerate(batch):
             out_i, state, iters, _ = slot(i)
@@ -1127,8 +1133,9 @@ def main(argv=None):
                          "engine pass)")
     cm.add_argument("--engine-capacity", type=int, default=4096,
                     help="per-(window, slab) tap budget of the Pallas "
-                         "engines; size it so the benchmark spill rate "
-                         "stays 0 (see BENCH_kernels.json)")
+                         "engines; over-capacity windows take an exact "
+                         "slow path, counted in "
+                         "repro_serving_spilled_taps_total")
     cm.add_argument("--sync", action="store_true",
                     help="use the synchronous FIFO-drain baseline")
     cm.add_argument("--budget-uj", type=float, default=None,
@@ -1170,6 +1177,8 @@ def main(argv=None):
                             "enables span/decision collection")
 
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "cmax":
         _run_cmax(args)
     else:

@@ -30,11 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # newer jax spells it jax.shard_map
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from .config import ModelConfig
 from .layers import Params, _dtype
 
@@ -166,9 +161,7 @@ def _moe_ep_shard(xt, router_w, wg, wu, wd, *, cfg: ModelConfig,
         wg = jax.lax.all_gather(wg, fsdp_axis, axis=1, tiled=True)
         wu = jax.lax.all_gather(wu, fsdp_axis, axis=1, tiled=True)
         wd = jax.lax.all_gather(wd, fsdp_axis, axis=2, tiled=True)
-    axis_size = getattr(jax.lax, "axis_size",
-                        lambda a: jax.lax.psum(1, a))   # jax 0.4.x compat
-    n_shards = int(axis_size(axis))
+    n_shards = jax.lax.axis_size(axis)
     E = cfg.n_experts
     E_loc = E // n_shards
     T_loc = xt.shape[0]
@@ -214,7 +207,7 @@ def moe_apply_ep(p: Params, x: jax.Array, cfg: ModelConfig, mesh,
     wgu_spec = P(ep_axis, f, None)
     wd_spec = P(ep_axis, None, f)
 
-    out = _shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), wgu_spec, wgu_spec, wd_spec),
         out_specs=tok_spec,

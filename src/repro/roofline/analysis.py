@@ -5,10 +5,10 @@
     collective term = collective bytes / (chips * link_bw)
 
 Hardware: TPU v5e-class — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
-The constants are sourced from the costmodel profile registry (the
-`[roofline]` section of `costmodel/profiles/tpu_v5e_estimate.toml`) via
-`HW.from_profile` / `default_hw()`; the dataclass defaults remain as a
-last-resort fallback so the module works even if the profile is removed.
+The constants come from the costmodel profile registry (the `[roofline]`
+section of `costmodel/profiles/tpu_v5e_estimate.toml`) via
+`HW.from_profile` / `default_hw()`. A profile that cannot be read is an
+error: no peak is ever assumed.
 
 Besides the model-estimation roofline (the three-term per-cell analysis
 below), this module carries the CMAX-KERNEL mode: analytic FLOPs/bytes
@@ -42,10 +42,10 @@ from repro.models.model import SHAPES, ShapeSpec
 
 @dataclasses.dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12        # bf16 / chip
-    hbm_bw: float = 819e9             # B/s / chip
-    link_bw: float = 50e9             # B/s / link (ICI)
-    hbm_per_chip: float = 16 * 2**30  # v5e: 16 GiB
+    peak_flops: float                 # FLOP/s / chip
+    hbm_bw: float                     # B/s / chip
+    link_bw: float                    # B/s / link (ICI)
+    hbm_per_chip: float               # bytes
 
     @classmethod
     def from_profile(cls, name_or_path: str = "tpu_v5e_estimate") -> "HW":
@@ -65,13 +65,9 @@ class HW:
 
 @functools.lru_cache(maxsize=1)
 def default_hw() -> HW:
-    """The default machine balance: the tpu_v5e_estimate profile, falling
-    back to the HW dataclass defaults if the profile cannot be loaded
-    (e.g. no TOML parser in the environment)."""
-    try:
-        return HW.from_profile("tpu_v5e_estimate")
-    except Exception:
-        return HW()
+    """The default machine balance: the tpu_v5e_estimate profile. Raises
+    (ProfileError, OSError) when the profile cannot be read."""
+    return HW.from_profile("tpu_v5e_estimate")
 
 
 # ----------------------------------------------------------------------
@@ -306,40 +302,40 @@ _F32 = 4.0
 _CHANNELS = 4          # IWE + 3 derivative images
 _VOTE_TAPS = 4         # bilinear footprint
 _WARP_FLOPS = 30.0     # Alg. 2: rotation, projection, scale, floor/frac
+_STATS_BLOCK = 8 * 128 * _F32   # the (8, 128) f32 stats block a kernel writes
 
 
 def cmax_megakernel_costs(Hs: int, Ws: int, n_slabs: int, cap: int,
                           k: int, rb: int, Wp: int) -> Dict[str, float]:
     """Batched megakernel, one window's share of one engine pass.
 
-    HBM in: the packed per-slab tap records (5 f32 planes of `cap` slots
-    per slab) + omega + FIR taps; HBM out: the (8,) stats vector. All
-    intermediate state (slab accumulators, line buffer, running sums)
-    lives in VMEM across the fused stages."""
+    HBM in: the packed per-slab tap records (an int32 pixel-id plane and
+    four f32 delta planes of `cap` slots per slab) + FIR taps; HBM out:
+    one (8, 128) f32 stats block. All intermediate state (slab
+    accumulators, line buffer, running sums) lives in VMEM across the
+    fused stages. The warp runs in the XLA binning prologue, once per
+    event, and is not charged to the kernel."""
     slots = float(n_slabs) * cap
-    hbm_read = 5.0 * slots * _F32 + 3 * _F32 + k * _F32
-    hbm_write = 8.0 * _F32
+    hbm_read = 5.0 * slots * _F32 + k * _F32
     slab_px = float(rb) * Wp
-    flops_warp = _WARP_FLOPS * slots
     flops_vote = 2.0 * slots * slab_px * _CHANNELS      # one-hot MXU dot
     flops_blur = 2.0 * (2 * k) * _CHANNELS * slab_px * n_slabs  # horiz+vert
     flops_stats = 12.0 * slab_px * n_slabs
-    return dict(flops=flops_warp + flops_vote + flops_blur + flops_stats,
-                hbm_bytes=hbm_read + hbm_write)
+    return dict(flops=flops_vote + flops_blur + flops_stats,
+                hbm_bytes=hbm_read + _STATS_BLOCK)
 
 
 def cmax_unfused_costs(Hs: int, Ws: int, n_events: int, cap_total: int,
                        k: int, Wp: int) -> Dict[str, float]:
     """Per-window kernel pair (iwe_accum then blur_stats): same arithmetic
     family as the megakernel, but the (4, Hs, Wp) channel stack crosses
-    HBM between the two pallas_calls (write + read back)."""
+    HBM between the two pallas_calls (write + read back). As for the
+    megakernel, the warp runs in the XLA prologue and is not charged."""
     img_bytes = _CHANNELS * Hs * Wp * _F32
     slots = float(cap_total)
-    hbm = 5.0 * slots * _F32 + 3 * _F32 + k * _F32 \
-        + 2.0 * img_bytes + 8.0 * _F32
+    hbm = 5.0 * slots * _F32 + k * _F32 + 2.0 * img_bytes + _STATS_BLOCK
     px = float(Hs) * Wp
-    flops = _WARP_FLOPS * slots + 2.0 * slots * px * _CHANNELS / max(
-        1, (Hs + k // 2 + 7) // 8) \
+    flops = 2.0 * slots * px * _CHANNELS / max(1, (Hs + k // 2 + 7) // 8) \
         + 2.0 * (2 * k) * _CHANNELS * px + 12.0 * px
     return dict(flops=flops, hbm_bytes=hbm)
 

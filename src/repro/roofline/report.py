@@ -8,7 +8,7 @@ import argparse
 import json
 from pathlib import Path
 
-from .analysis import HW, summarize_cell
+from .analysis import summarize_cell
 
 
 def fmt_bytes(b):
@@ -69,7 +69,7 @@ def dryrun_table(recs) -> str:
     return "\n".join(rows)
 
 
-def roofline_table(recs, hw: HW = HW()) -> str:
+def roofline_table(recs, hw=None) -> str:
     rows = ["| cell | t_compute | t_memory | t_collective | dominant | "
             "useful (6ND/HLO) | fits | next lever |",
             "|---|---|---|---|---|---|---|---|"]
@@ -96,7 +96,7 @@ def roofline_table(recs, hw: HW = HW()) -> str:
     return "\n".join(rows)
 
 
-def pick_hillclimb(recs, hw: HW = HW()):
+def pick_hillclimb(recs, hw=None):
     """The three §Perf cells: worst compute fraction (train), most
     collective-bound, most representative."""
     summaries = [s for s in (summarize_cell(r, hw) for r in recs) if s]

@@ -163,6 +163,12 @@ class Workload:
         (the virtual-time DES drives the scheduler with no array work)."""
         raise NotImplementedError
 
+    def spilled_taps(self, result, n_real: int) -> int:
+        """Work the batch's fast path could not hold and recomputed on an
+        exact slow path, summed over the first `n_real` (non-fill) slots;
+        0 for a workload without such a path."""
+        return 0
+
 
 # ---------------------------------------------------------------------------
 # CMAX: the paper's contrast-maximization pipeline as a plugin.
@@ -342,6 +348,11 @@ class CmaxWorkload(Workload):
         import types
         return types.SimpleNamespace(
             omega=np.zeros((batch_b, 3), np.float32), stages=())
+
+    def spilled_taps(self, result, n_real):
+        # megakernel taps over the per-slab capacity (StageTrace.spilled)
+        return int(sum(np.asarray(tr.spilled)[:n_real].sum()
+                       for tr in getattr(result, "stages", ())))
 
 
 # ---------------------------------------------------------------------------

@@ -20,28 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # newer jax spells it jax.shard_map
-    _shard_map = jax.shard_map
-except AttributeError:                  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 PyTree = object
-
-
-def _device_varying(x, axes):
-    """Mark x device-varying over `axes` for the fori_loop type check.
-
-    Only jax versions with the varying-type system (jax.lax.pvary /
-    pcast) need — or have — the cast; on older versions replication is
-    untyped and this is an identity.
-    """
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axes)
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axes, to="varying")
-    return x
 
 
 def _pipe_shard(params_loc: PyTree, mbs: jax.Array, *,
@@ -70,8 +49,8 @@ def _pipe_shard(params_loc: PyTree, mbs: jax.Array, *,
 
     # initial carries must be marked as device-varying for the fori_loop
     # type check (they become varying through ppermute/axis_index)
-    recv0 = _device_varying(jnp.zeros_like(mbs[0]), (axis,))
-    out0 = _device_varying(jnp.zeros_like(mbs), (axis,))
+    recv0 = jax.lax.pcast(jnp.zeros_like(mbs[0]), (axis,), to="varying")
+    out0 = jax.lax.pcast(jnp.zeros_like(mbs), (axis,), to="varying")
     _, out = jax.lax.fori_loop(0, M + n_stages - 1, tick, (recv0, out0))
     # only the last stage holds real outputs; replicate via masked psum
     out = jnp.where(sid == n_stages - 1, out, 0.0)
@@ -95,14 +74,12 @@ def pipeline_apply(stage_fn: Callable, stacked_params: PyTree,
 
     body = functools.partial(_pipe_shard, stage_fn=stage_fn, n_stages=S,
                              axis=axis)
-    pspec = jax.tree.map(lambda _: P(axis), stacked_params)
-    out = _shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(axis), stacked_params),
                   P(*([None] * (mbs.ndim)))),
         out_specs=P(*([None] * mbs.ndim)),
     )(stacked_params, mbs)
-    del pspec
     return out.reshape(B, *x.shape[1:])
 
 

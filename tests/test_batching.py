@@ -238,10 +238,10 @@ def test_service_with_mesh():
     """mesh-backed service routes through estimate_batch_sharded and
     matches the per-window reference (1-device mesh in-process; the
     multi-device case is tests/test_sharding_subprocess.py)."""
-    import jax
+    from repro.launch.mesh import make_mesh
     cam = small_camera()
     cfg = fast_cfg(cam)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pol = ev_data.pow2_policy(min_bucket=128, max_bucket=512)
     svc = BatchedEstimationService(cfg, policy=pol, max_batch=2, mesh=mesh)
     streams = ragged_streams(cam, 2, n_windows=2)

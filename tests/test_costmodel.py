@@ -58,7 +58,6 @@ def test_csv_roundtrip(tmp_path):
 
 
 def test_toml_roundtrip(tmp_path):
-    pytest.importorskip("tomli")
     p = tmp_path / "rt.toml"
     _write_toml(p, _sections())
     assert read_profile_dict(str(p)) == _sections()

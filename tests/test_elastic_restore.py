@@ -12,6 +12,7 @@ def test_checkpoint_reshards_onto_new_mesh(tmp_path):
     code = f"""
         import jax, numpy as np
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as ckpt
 
@@ -21,7 +22,7 @@ def test_checkpoint_reshards_onto_new_mesh(tmp_path):
         ckpt.save(r"{tmp_path}", 3, tree, extra={{"next_step": 3}})
 
         # phase 2: "new fleet" — restore sharded onto a 2x4 mesh
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         sh = {{"w": NamedSharding(mesh, P("data", "model")),
               "b": NamedSharding(mesh, P("model"))}}
         restored, extra = ckpt.restore(r"{tmp_path}", tree, shardings=sh)

@@ -6,9 +6,10 @@ Contracts pinned here:
     engine pass (allclose: the one-hot MXU contraction sums in a different
     order than scatter-add, so bitwise equality vs the reference is not
     on the table);
-  * batch invariance — a single megakernel call is slotwise deterministic:
-    a window's (8,) stats are bit-identical whether it runs as B=1 or as
-    any slot of a larger batch;
+  * batch invariance — one engine pass (binning prologue + megakernel) is
+    slotwise deterministic: a window's (8,) stats are bit-identical whether
+    it runs as B=1 or as any slot of a larger batch (interpreted here;
+    chip_smoke.py reports the same on the chip);
   * fill invariance — at FIXED batch size (the serving layer buckets B),
     a slot's full pipeline result is bit-identical no matter what occupies
     the other slots (the invariant out-of-order refill relies on);
@@ -31,9 +32,10 @@ from repro.core.geometry import warp_events
 from repro.core.pipeline import estimate_batch_budgeted, make_engine_pass
 from repro.core.types import ENGINES
 from repro.kernels import batched_engine_pass, batched_engine_stats
+from repro.kernels.iwe_accum import CHUNK
 from helpers import random_window, small_camera
 
-CAP, CHUNK = 1024, 128
+CAP = 1024
 
 
 def _stack(wins):
@@ -69,8 +71,7 @@ def test_megakernel_matches_reference_engine(scale, k):
                     [-1.5, 2.0, 0.3]], jnp.float32)
     # the tiny camera has only 2 row slabs at s=0.25 — budget generously
     v_mk, g_mk, spilled = batched_engine_pass(
-        batch, om, cam, scale, k, 0.5 + 0.25 * k / 3, capacity=2048,
-        chunk=CHUNK)
+        batch, om, cam, scale, k, 0.5 + 0.25 * k / 3, capacity=2048)
     assert int(jnp.sum(spilled)) == 0
 
     stage = StageConfig(scale=scale, tau=1e-3, max_iters=3, blur_taps=k,
@@ -85,7 +86,7 @@ def test_megakernel_matches_reference_engine(scale, k):
 
 
 def test_megakernel_batch_invariance_bitwise():
-    """One kernel call: stats of a window are bit-identical at B=1 and as
+    """One engine pass: stats of a window are bit-identical at B=1 and as
     any slot of a B=4 batch."""
     cam = small_camera()
     wins = [random_window(300, cam=cam, seed=20 + i, valid_frac=0.9)
@@ -93,10 +94,10 @@ def test_megakernel_batch_invariance_bitwise():
     om = jnp.array([[0.5, -0.2, 0.9], [1.0, 0.0, -0.5],
                     [0.0, 1.2, 0.0], [-0.7, -0.7, 0.7]], jnp.float32)
     out_b = batched_engine_stats(_stack(wins), om, cam, 0.5, 5, 0.75,
-                                 capacity=CAP, chunk=CHUNK)
+                                 capacity=CAP)
     for i, w in enumerate(wins):
         out_1 = batched_engine_stats(_stack([w]), om[i:i + 1], cam, 0.5, 5,
-                                     0.75, capacity=CAP, chunk=CHUNK)
+                                     0.75, capacity=CAP)
         assert bool(jnp.all(out_1.stats[0] == out_b.stats[i]))
         assert int(out_1.spilled[0]) == int(out_b.spilled[i])
 
@@ -112,11 +113,11 @@ def test_megakernel_padded_and_dead_slots():
     w0 = jnp.where(dead.valid, 1.0, 0.0)  # mask, as sort_events would
     full = batched_engine_stats(
         _stack(live + [random_window(256, cam=cam, seed=99)]), om, cam,
-        1.0, 9, 1.0, capacity=CAP, chunk=CHUNK)
+        1.0, 9, 1.0, capacity=CAP)
     holey = batched_engine_stats(
         _stack(live + [dead]), om, cam, 1.0, 9, 1.0,
         weights=jnp.stack([jnp.ones((256,))] * 2 + [w0]),
-        capacity=CAP, chunk=CHUNK)
+        capacity=CAP)
     for i in range(2):
         assert bool(jnp.all(full.stats[i] == holey.stats[i]))
     assert bool(jnp.all(jnp.isfinite(holey.stats[2])))
@@ -125,16 +126,16 @@ def test_megakernel_padded_and_dead_slots():
 
 def test_spill_counter_matches_numpy_accounting():
     cam = small_camera()
-    rb, capacity, chunk = 8, 128, 128
+    rb, capacity = 8, CHUNK
     ev = random_window(600, cam=cam, seed=7)
     om = jnp.array([[0.3, -0.6, 1.4]], jnp.float32)
     scale, k = 1.0, 9
     out = batched_engine_stats(_stack([ev]), om, cam, scale, k, 1.0,
-                               rb=rb, capacity=capacity, chunk=chunk)
+                               rb=rb, capacity=capacity)
     # independent numpy mirror of the slab-binning prologue
     Hs, _ = cam.grid(scale)
     n_slabs = -(-(Hs + k // 2) // rb)
-    cap = max(capacity, chunk)
+    cap = capacity
     w = warp_events(ev, om[0], cam, scale)
     pw = np.asarray(ev.p, np.float32)     # weights=None -> all ones
     contributing = np.asarray(w.in_range) & (pw != 0.0)
@@ -144,6 +145,61 @@ def test_spill_counter_matches_numpy_accounting():
     expect = int(np.maximum(cnt - cap, 0).sum())
     assert int(out.spilled[0]) == expect
     assert expect > 0, "test should exercise a genuine spill"
+
+
+def test_spilled_windows_take_exact_slow_path():
+    """A window whose slabs overflow is recomputed by the reference
+    datapath: at a capacity that spills, the pass still matches the
+    reference engine, and a window that fits keeps the kernel's stats."""
+    cam = small_camera()
+    n = 1200
+    batch = _stack([random_window(n, cam=cam, seed=80),
+                    random_window(n, cam=cam, seed=81, valid_frac=0.1)])
+    om = jnp.array([[0.3, -0.6, 1.4], [0.2, 0.1, -0.3]], jnp.float32)
+    v_t, g_t, sp_t = batched_engine_pass(batch, om, cam, 1.0, 9, 1.0,
+                                         capacity=CHUNK)
+    v_r, g_r, sp_r = batched_engine_pass(batch, om, cam, 1.0, 9, 1.0,
+                                         capacity=4 * n)
+    assert int(sp_t[0]) > 0 and int(sp_t[1]) == 0
+    assert int(jnp.sum(sp_r)) == 0
+    stage = StageConfig(scale=1.0, tau=1e-3, max_iters=3, blur_taps=9,
+                        blur_sigma=1.0, keep_ratio=1.0)
+    ref = jax.vmap(make_engine_pass(cam, stage, jnp.float32))
+    v_ref, g_ref = ref(batch, jnp.ones((2, n), jnp.float32), om)
+    np.testing.assert_allclose(np.asarray(v_t), np.asarray(v_ref),
+                               rtol=1e-4)
+    s = float(jnp.max(jnp.abs(g_ref))) + 1e-12
+    np.testing.assert_allclose(np.asarray(g_t) / s, np.asarray(g_ref) / s,
+                               atol=1e-4)
+    # the window that fits is untouched by its neighbour's slow path
+    assert float(v_t[1]) == float(v_r[1])
+    assert bool(jnp.all(g_t[1] == g_r[1]))
+
+
+def test_service_counts_spilled_taps():
+    """Served windows that overflow a slab stay exact and are counted in
+    the service's repro_serving_spilled_taps_total."""
+    from repro.data import events as ev_data
+    from repro.launch.serve import AsyncBatchedEstimationService
+    cam = small_camera()
+    n = 1024
+    wins = [random_window(n, cam=cam, seed=90 + i) for i in range(2)]
+    cfg = dataclasses.replace(_tiny_cfg(cam), engine_capacity=CHUNK)
+    svc = AsyncBatchedEstimationService(
+        cfg, policy=ev_data.single_policy(n), max_batch=2)
+    for i, w in enumerate(wins):
+        svc.submit(f"s{i}", w)
+    responses = svc.drain()
+    assert [r.status for r in responses] == ["ok", "ok"]
+    counter = svc.telemetry.registry.counter(
+        "repro_serving_spilled_taps_total")
+    assert counter.value > 0
+    ref = estimate_batch(_stack(wins), jnp.zeros((2, 3), jnp.float32),
+                         _tiny_cfg(cam, "reference"))
+    for r in responses:
+        np.testing.assert_allclose(
+            np.asarray(r.omega), np.asarray(ref.omega[int(r.stream_id[1:])]),
+            atol=5e-4)
 
 
 # ----------------------------------------------------------------------
@@ -192,8 +248,10 @@ def test_estimate_batch_fill_invariance_bitwise():
 
 
 def test_estimate_window_close_to_batch_slot():
-    """B=1 vs slot-of-B agree numerically (XLA fuses the binning prologue
-    differently per batch shape, so cross-B is allclose, not bitwise)."""
+    """B=1 vs slot-of-B agree numerically. The engine pass is bitwise
+    batch-invariant, but the vmapped pipeline around it (sorting, CG-PR
+    updates) compiles differently per batch shape and can differ in the
+    last bit, so across batch sizes the contract is allclose."""
     cam = small_camera()
     cfg = _tiny_cfg(cam)
     wins = [random_window(256, cam=cam, seed=60 + i) for i in range(3)]

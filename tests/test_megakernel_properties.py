@@ -22,6 +22,7 @@ from repro.core import CmaxConfig, EventWindow, StageConfig
 from repro.core.geometry import warp_events
 from repro.core.pipeline import estimate_streams, make_engine_pass
 from repro.kernels import batched_engine_pass, batched_engine_stats
+from repro.kernels.iwe_accum import CHUNK
 from helpers import random_window, small_camera
 
 
@@ -47,8 +48,7 @@ def test_megakernel_equivalence_sweep(n, scale, capacity, valid_frac, b):
     weights = jnp.stack([jnp.where(w.valid, 1.0, 0.0) for w in wins])
 
     v_mk, g_mk, spilled = batched_engine_pass(
-        batch, om, cam, scale, k, 1.0, weights=weights, capacity=capacity,
-        chunk=128)
+        batch, om, cam, scale, k, 1.0, weights=weights, capacity=capacity)
     assert int(jnp.sum(spilled)) == 0
 
     stage = StageConfig(scale=scale, tau=1e-3, max_iters=3, blur_taps=k,
@@ -80,10 +80,10 @@ def test_spill_accounting_matches_numpy(n, capacity, rb, seed):
     rng = np.random.default_rng(seed)
     om = jnp.asarray(rng.uniform(-1.0, 1.0, (1, 3)).astype(np.float32))
     out = batched_engine_stats(_stack([ev]), om, cam, scale, k, 1.0,
-                               rb=rb, capacity=capacity, chunk=128)
+                               rb=rb, capacity=capacity)
     Hs, _ = cam.grid(scale)
     n_slabs = -(-(Hs + k // 2) // rb)
-    cap = -(-max(capacity, 128) // 128) * 128
+    cap = -(-max(capacity, CHUNK) // CHUNK) * CHUNK
     w = warp_events(ev, om[0], cam, scale)
     contributing = np.asarray(w.in_range) & \
         (np.asarray(ev.p, np.float32) != 0.0)
@@ -93,7 +93,7 @@ def test_spill_accounting_matches_numpy(n, capacity, rb, seed):
     assert int(out.spilled[0]) == int(np.maximum(cnt - cap, 0).sum())
 
     roomy = batched_engine_stats(_stack([ev]), om, cam, scale, k, 1.0,
-                                 rb=rb, capacity=4 * n, chunk=128)
+                                 rb=rb, capacity=4 * n)
     assert int(roomy.spilled[0]) == 0
 
 

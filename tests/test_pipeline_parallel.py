@@ -24,10 +24,11 @@ def test_pipeline_matches_sequential_and_differentiates():
     out = run_py("""
         import jax, numpy as np
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.train.pipeline import (pipeline_apply,
                                           sequential_reference)
 
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         L, D = 8, 16          # 8 layers -> 4 stages x 2 layers
         key = jax.random.key(0)
         W = jax.random.normal(key, (L, D, D)) * (0.5 / np.sqrt(D))

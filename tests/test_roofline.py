@@ -16,9 +16,16 @@ from repro.roofline.analysis import (HW, analytic_flops, roofline_terms)
 
 def _flops_of(fn, *args):
     c = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(c, (list, tuple)):
-        c = c[0]
     return float(c["flops"])
+
+
+@pytest.mark.parametrize("profile", ["no_such_profile", "paper_fpga_45nm"])
+def test_unreadable_roofline_profile_raises(profile):
+    """No peak is ever assumed: a profile that does not exist, or has no
+    [roofline] section, is an error."""
+    from repro.costmodel.profiles import ProfileError
+    with pytest.raises(ProfileError):
+        HW.from_profile(profile)
 
 
 def test_analytic_forward_matches_xla_dense():
