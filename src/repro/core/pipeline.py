@@ -22,6 +22,15 @@ windows; `estimate_sequence` scans a full sequence with warm starts.
 
 The returned trace carries everything the energy/latency model (energy.py)
 needs: per-stage engine-pass counts and retained-event counts.
+
+Device scopes (`jax.named_scope`, metadata only: they name the compiled
+program's operations in a profile and change nothing XLA fuses), in both
+the per-window and the lockstep-batched path:
+
+    cmax.stage{k}          one stage's residence
+      cmax.sort            the stage-entry `sort_events`
+      cmax.engine_pass     each engine call, entry pass and loop body
+      cmax.update          the CG-PR / gradient step
 """
 from __future__ import annotations
 
@@ -142,11 +151,13 @@ def _run_stage(ev: EventWindow, omega: jax.Array, opt_state: cgpr.CgprState,
     top of the static `max_iters` — the hook the budget scheduler
     (costmodel, DESIGN.md §5) uses to spend an energy/latency budget
     without recompiling per allocation."""
-    tables = sort_events(ev, omega, cam, stage)
+    with jax.named_scope("cmax.sort"):
+        tables = sort_events(ev, omega, cam, stage)
     weights = tables.weights
 
     # Alg. 1 line 2: V_prev <- V_s(omega)  (entry pass, also primes grad)
-    v_entry, g_entry = engine(ev, weights, omega)
+    with jax.named_scope("cmax.engine_pass"):
+        v_entry, g_entry = engine(ev, weights, omega)
 
     if cfg.adaptive:
         max_iters = stage.max_iters
@@ -177,8 +188,10 @@ def _run_stage(ev: EventWindow, omega: jax.Array, opt_state: cgpr.CgprState,
     def body(carry):
         st, v_prev, g, it, _, hist, alpha = carry
         om, ost = st
-        om_p, ost_p = update(om, g, ost, alpha)      # propose
-        v_p, g_p = engine(ev, weights, om_p)         # one engine pass
+        with jax.named_scope("cmax.update"):
+            om_p, ost_p = update(om, g, ost, alpha)      # propose
+        with jax.named_scope("cmax.engine_pass"):
+            v_p, g_p = engine(ev, weights, om_p)         # one engine pass
         hist = hist.at[it].set(v_p)
         improved = v_p > v_prev
         sel = lambda a, b: jax.tree.map(
@@ -234,12 +247,14 @@ def _run_stage_batched(ev: EventWindow, omega: jax.Array,
     batching rule produces, so traces match the vmapped reference
     bit-for-bit). `iter_cap`, when given, is (B,) int32."""
     B = omega.shape[0]
-    tables = jax.vmap(lambda x, y, t, p, vl, om: sort_events(
-        EventWindow(x, y, t, p, vl), om, cam, stage))(
-        ev.x, ev.y, ev.t, ev.p, ev.valid, omega)
+    with jax.named_scope("cmax.sort"):
+        tables = jax.vmap(lambda x, y, t, p, vl, om: sort_events(
+            EventWindow(x, y, t, p, vl), om, cam, stage))(
+            ev.x, ev.y, ev.t, ev.p, ev.valid, omega)
     weights = tables.weights                              # (B, N)
 
-    v_entry, g_entry, spill_entry = engine_b(ev, weights, omega)
+    with jax.named_scope("cmax.engine_pass"):
+        v_entry, g_entry, spill_entry = engine_b(ev, weights, omega)
 
     if cfg.adaptive:
         max_iters = stage.max_iters
@@ -265,8 +280,10 @@ def _run_stage_batched(ev: EventWindow, omega: jax.Array,
         st, v_prev, g, it, done, hist, alpha, spill = carry
         active = (~done) & (it < cap)                     # (B,)
         om, ost = st
-        om_p, ost_p = update(om, g, ost, alpha)           # propose (all B)
-        v_p, g_p, spill_p = engine_b(ev, weights, om_p)   # ONE kernel launch
+        with jax.named_scope("cmax.update"):
+            om_p, ost_p = update(om, g, ost, alpha)       # propose (all B)
+        with jax.named_scope("cmax.engine_pass"):         # ONE kernel launch
+            v_p, g_p, spill_p = engine_b(ev, weights, om_p)
         it_c = jnp.clip(it, 0, max_iters - 1)
         hist = hist.at[rows, it_c].set(
             jnp.where(active, v_p, hist[rows, it_c]))
@@ -317,9 +334,10 @@ def _estimate_batch_lockstep(windows: EventWindow, omega0s: jax.Array,
         # CG restarts at each stage, as in the per-window path.
         opt_state = jax.vmap(lambda _: cgpr.init_state(3, cfg.dtype))(
             jnp.arange(B))
-        omega, opt_state, tr = _run_stage_batched(
-            windows, omega, opt_state, cam, stage, cfg, si, engine_b,
-            iter_cap=None if iter_caps is None else iter_caps[:, si])
+        with jax.named_scope(f"cmax.stage{si}"):
+            omega, opt_state, tr = _run_stage_batched(
+                windows, omega, opt_state, cam, stage, cfg, si, engine_b,
+                iter_cap=None if iter_caps is None else iter_caps[:, si])
         traces.append(tr)
     return WindowResult(omega=omega, stages=tuple(traces))
 
@@ -342,8 +360,9 @@ def estimate_window(ev: EventWindow, omega0: jax.Array,
         # CG history does not transfer across resolutions (the objective
         # surface changes scale) — restart CG at each stage, as HW does.
         opt_state = cgpr.init_state(3, cfg.dtype)
-        omega, opt_state, tr = _run_stage(ev, omega, opt_state, cam, stage,
-                                          cfg, si, engine)
+        with jax.named_scope(f"cmax.stage{si}"):
+            omega, opt_state, tr = _run_stage(ev, omega, opt_state, cam,
+                                              stage, cfg, si, engine)
         traces.append(tr)
     return WindowResult(omega=omega, stages=tuple(traces))
 
@@ -372,9 +391,10 @@ def estimate_window_budgeted(ev: EventWindow, omega0: jax.Array,
     for si, stage in enumerate(cfg.stages):
         engine = _make_engine_for(cfg, cam, stage)
         opt_state = cgpr.init_state(3, cfg.dtype)
-        omega, opt_state, tr = _run_stage(ev, omega, opt_state, cam, stage,
-                                          cfg, si, engine,
-                                          iter_cap=iter_caps[si])
+        with jax.named_scope(f"cmax.stage{si}"):
+            omega, opt_state, tr = _run_stage(ev, omega, opt_state, cam,
+                                              stage, cfg, si, engine,
+                                              iter_cap=iter_caps[si])
         traces.append(tr)
     return WindowResult(omega=omega, stages=tuple(traces))
 

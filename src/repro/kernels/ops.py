@@ -11,6 +11,9 @@
                         batch) + ONE (batch, slab)-grid pallas_call fusing
                         vote/accumulate/blur/stats, then Eq. 12; windows
                         that overflow a slab take the reference slow path.
+                        Device scopes: `cmax.bin_taps` (the prologue),
+                        `cmax.megakernel` (the pallas_call) and
+                        `cmax.spill_slow_path` (the slow path's branch).
 
 The kernels compile on a TPU and are interpreted on the CPU
 (kernels/backend.py decides). The oracles live in ref.py; tests sweep
@@ -243,20 +246,23 @@ def batched_engine_stats(ev: EventWindow, omega: jax.Array, cam: Camera,
         weights = jnp.ones_like(ev.x, dtype=jnp.float32)
     omega = omega.astype(jnp.float32)
 
-    (pix, dv), spilled = jax.vmap(
-        lambda x, y, t, p, v, om, wt: _bin_taps_one(
-            EventWindow(x, y, t, p, v), om, wt, cam, scale, rb, n_slabs, Wp,
-            cap))(ev.x, ev.y, ev.t, ev.p, ev.valid, omega, weights)
+    with jax.named_scope("cmax.bin_taps"):
+        (pix, dv), spilled = jax.vmap(
+            lambda x, y, t, p, v, om, wt: _bin_taps_one(
+                EventWindow(x, y, t, p, v), om, wt, cam, scale, rb, n_slabs,
+                Wp, cap))(ev.x, ev.y, ev.t, ev.p, ev.valid, omega, weights)
 
     fir = gaussian_taps(k, sigma, jnp.float32)
-    block = megakernel_stats(pix, dv.astype(dtype), fir, rb=rb, k=k, H=Hs,
-                             W=Ws, Wp=Wp)
+    with jax.named_scope("cmax.megakernel"):
+        block = megakernel_stats(pix, dv.astype(dtype), fir, rb=rb, k=k,
+                                 H=Hs, W=Ws, Wp=Wp)
     stats = stats_from_block(block)
 
     def slow_path(stats):
-        ref = batched_engine_stats_ref(ev, omega, cam, scale, k, sigma,
-                                       weights.astype(jnp.float32))
-        return jnp.where((spilled > 0)[:, None], ref, stats)
+        with jax.named_scope("cmax.spill_slow_path"):
+            ref = batched_engine_stats_ref(ev, omega, cam, scale, k, sigma,
+                                           weights.astype(jnp.float32))
+            return jnp.where((spilled > 0)[:, None], ref, stats)
 
     stats = jax.lax.cond(jnp.any(spilled > 0), slow_path, lambda s: s,
                          stats)

@@ -275,6 +275,31 @@ class _InFlight:
     batch_b: int
     t_dispatch: float
     caps: Optional[np.ndarray] = None   # (B, S) budget caps, for telemetry
+    batch: int = 0                      # the service's id of this batch
+
+
+_XLA_COMPILES = None
+
+
+def _xla_compiles() -> int:
+    """XLA backend compiles in this process so far: `jax.monitoring`'s
+    backend-compile event, counted by a listener registered on first use.
+    A program loaded from JAX's in-memory or persistent cache is not a
+    compile. The count is process-wide: a compile on another thread
+    between two readings is counted too."""
+    global _XLA_COMPILES
+    if _XLA_COMPILES is None:
+        import jax
+        from jax._src import dispatch
+        _XLA_COMPILES = [0]
+        event = dispatch.BACKEND_COMPILE_EVENT
+
+        def on_duration(name, duration, **_):
+            if name == event:
+                _XLA_COMPILES[0] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return _XLA_COMPILES[0]
 
 
 # ---------------------------------------------------------------------------
@@ -298,6 +323,13 @@ class _ServingMetrics:
         self.batches = c("repro_serving_batches_total", "batches dispatched")
         self.compiles = c("repro_serving_compiles_total",
                           "executable-cache misses (new shape classes)")
+        self.xla_compiles = c("repro_serving_xla_compiles_total",
+                              "XLA backend compiles inside a dispatch")
+        self.slot_passes = c("repro_serving_slot_passes_total",
+                             "engine passes the device ran: batch slots x "
+                             "the slowest real window's passes, per stage")
+        self.window_passes = c("repro_serving_window_passes_total",
+                               "engine passes the served windows needed")
         self.event_slots = c("repro_serving_event_slots_total",
                              "padded slots dispatched (bucket_n * batch_b)")
         self.raw_events = c("repro_serving_raw_events_total",
@@ -372,6 +404,19 @@ class _StatsView(MutableMapping):
 
     def __repr__(self):
         return repr(dict(self))
+
+
+def _count_passes(m: _ServingMetrics, workload, iters, batch_b: int
+                  ) -> None:
+    """Engine passes of one harvested batch. The batch runs in lockstep:
+    every slot, fill slots included, runs as many passes per stage as the
+    batch's slowest real window, while each window needed only its own."""
+    per_window = [workload.engine_passes(it) for it in iters]
+    if not per_window or not per_window[0]:
+        return
+    p = np.asarray(per_window, np.int64)            # (windows, stages)
+    m.slot_passes.inc(batch_b * int(p.max(axis=0).sum()))
+    m.window_passes.inc(int(p.sum()))
 
 
 def _batch_class(b: int, max_batch: int, mesh) -> int:
@@ -467,6 +512,7 @@ class AsyncBatchedEstimationService:
         self._inflight: Deque[_InFlight] = deque()
         self._ready: List[WindowResponse] = []
         self._order = 0
+        self._next_batch = 0    # batch ids, given at launch
         self._cache: Dict[Tuple[int, int, bool], object] = {}
 
     @property
@@ -602,6 +648,13 @@ class AsyncBatchedEstimationService:
         cands = self._admissible()
         if not cands:
             return False
+        batch_id = self._next_batch
+        self._next_batch += 1
+        with self._tracer.region("serve.launch", batch=batch_id):
+            self._launch(cands, batch_id)
+        return True
+
+    def _launch(self, cands: List[WindowRequest], batch_id: int) -> None:
         cands.sort(key=lambda r: (-r.priority, r.order))
         leader = cands[0]
         bucket_n = leader.bucket_n
@@ -623,36 +676,44 @@ class AsyncBatchedEstimationService:
                       else self._warm.get(r.stream_id,
                                           self.workload.default_state())
                       for r in batch]
-            ev_batch, om_batch, n_fill = self.workload.make_batch(
-                [r.window for r in batch], states, bucket_n, batch_b)
+            with self._tracer.region("serve.make_batch", batch=batch_id):
+                ev_batch, om_batch, n_fill = self.workload.make_batch(
+                    [r.window for r in batch], states, bucket_n, batch_b)
         else:
             ev_batch = om_batch = None    # virtual-time simulation
 
-        pre_compiles = self._m.compiles.value
         fn = self._executable(bucket_n, batch_b, budgeted=caps is not None)
-        compiled = self._m.compiles.value != pre_compiles
-        if caps is not None:
-            # the caps are per-dispatch data; the workload closes them over
-            # so every executor sees the uniform fn(data, state) signature
-            fn = self.workload.attach_caps(fn, caps)
-        handle = self.executor.submit(fn, ev_batch, om_batch,
-                                      bucket_n, batch_b)
+        with self._tracer.region("serve.dispatch", batch=batch_id):
+            pre_compiles = _xla_compiles()
+            if caps is not None:
+                # the caps are per-dispatch data; the workload closes them
+                # over so every executor sees the uniform fn(data, state)
+                # signature
+                fn = self.workload.attach_caps(fn, caps)
+            handle = self.executor.submit(fn, ev_batch, om_batch,
+                                          bucket_n, batch_b)
+            compiles = _xla_compiles() - pre_compiles
         t_dispatch = self.clock.now()
         for r in batch:
             self._tracer.mark(r.stream_id, r.seq, "dispatch", t=t_dispatch,
-                              batch_b=batch_b, compile=compiled)
+                              batch_b=batch_b, batch=batch_id,
+                              compile=compiles > 0)
         self._inflight.append(_InFlight(batch, handle, bucket_n, batch_b,
-                                        t_dispatch, caps))
+                                        t_dispatch, caps, batch_id))
+        self._m.xla_compiles.inc(compiles)
         self._m.batches.inc()
         self._m.event_slots.inc(bucket_n * batch_b)
         self._m.raw_events.inc(sum(self.workload.size_of(r.window)
                                    for r in batch))
         self._m.fill_slots.inc(n_fill)
-        return True
 
     # -- completion ------------------------------------------------------------
 
     def _finish(self, fb: _InFlight) -> None:
+        with self._tracer.region("serve.harvest", batch=fb.batch):
+            self._harvest_batch(fb)
+
+    def _harvest_batch(self, fb: _InFlight) -> None:
         res = self.executor.wait(fb.handle)
         now = self.clock.now()
         track_gain = any(q.budgeted for q in self.qos_classes.values())
@@ -661,8 +722,10 @@ class AsyncBatchedEstimationService:
             self.workload.spilled_taps(res, len(fb.requests)))
         meta = self.workload.decision_meta(res) \
             if self._decisions.enabled else None
+        all_iters = []
         for i, r in enumerate(fb.requests):
             out, state, iters, gain = slot(i)
+            all_iters.append(iters)
             if state is not None:    # None = data-free run; keep old state
                 self._warm[r.stream_id] = state
             self._busy.discard(r.stream_id)
@@ -674,15 +737,16 @@ class AsyncBatchedEstimationService:
             self._tracer.finish(r.stream_id, r.seq, "harvest", "ok",
                                 iters=iters, t=now)
             if self._decisions.enabled:
-                self._record_decisions(r, iters, fb.caps, i, meta)
+                self._record_decisions(r, iters, fb.caps, fb.batch, i, meta)
             self._ready.append(WindowResponse(
                 r.stream_id, r.seq, out, iters,
                 fb.bucket_n, fb.batch_b, status="ok",
                 t_submit=r.t_submit, t_done=now, qos=r.qos))
         self._m.windows.inc(len(fb.requests))
+        _count_passes(self._m, self.workload, all_iters, fb.batch_b)
 
     def _record_decisions(self, r: WindowRequest, iters: Tuple[int, ...],
-                          caps: Optional[np.ndarray], i: int,
+                          caps: Optional[np.ndarray], batch: int, i: int,
                           meta: Optional[dict]) -> None:
         """One decision record per stage of one served window: iterations
         spent vs the budget cap and static bound, the measured stage gain,
@@ -697,7 +761,7 @@ class AsyncBatchedEstimationService:
             mi = int(max_iters[s]) if max_iters is not None else None
             g = float(gains[i, s]) if gains is not None else None
             self._decisions.record(
-                r.stream_id, r.seq, s, int(it), cap, mi, g,
+                r.stream_id, r.seq, batch, i, s, int(it), cap, mi, g,
                 residence_verdict(it, cap, mi))
 
     def _harvest(self, block: bool = False) -> bool:
@@ -707,7 +771,9 @@ class AsyncBatchedEstimationService:
         if block and self._inflight and \
                 not any(self.executor.done(fb.handle)
                         for fb in self._inflight):
-            self.executor.wait(self._inflight[0].handle)
+            oldest = self._inflight[0]
+            with self._tracer.region("serve.wait", batch=oldest.batch):
+                self.executor.wait(oldest.handle)
         progressed = False
         still: Deque[_InFlight] = deque()
         for fb in self._inflight:
@@ -725,14 +791,16 @@ class AsyncBatchedEstimationService:
         """One non-blocking scheduler turn: harvest finished batches, shed
         expired requests, refill the in-flight window from the queue.
         Returns the responses completed since the last call."""
-        self._harvest(block=False)
-        self._shed_expired()
-        while len(self._inflight) < self.max_in_flight and self._launch_one():
-            pass
-        self._m.queue_depth.set(len(self._queue))
-        self._m.inflight_batches.set(len(self._inflight))
-        out, self._ready = self._ready, []
-        return out
+        with self._tracer.region("serve.poll"):
+            self._harvest(block=False)
+            self._shed_expired()
+            while len(self._inflight) < self.max_in_flight \
+                    and self._launch_one():
+                pass
+            self._m.queue_depth.set(len(self._queue))
+            self._m.inflight_batches.set(len(self._inflight))
+            out, self._ready = self._ready, []
+            return out
 
     def drain(self) -> List[WindowResponse]:
         """Poll until the queue and the in-flight window are both empty,
@@ -806,6 +874,7 @@ class BatchedEstimationService:
         self._queue: Deque[WindowRequest] = deque()
         self._seq: Dict[str, int] = {}
         self._warm: Dict[str, object] = {}      # per-stream carried state
+        self._next_batch = 0    # batch ids, given at launch
         self._cache: Dict[Tuple[int, int], object] = {}
 
     @property
@@ -894,44 +963,59 @@ class BatchedEstimationService:
         batch = self._collect()
         if not batch:
             return []
+        batch_id = self._next_batch
+        self._next_batch += 1
         bucket_n = batch[0].bucket_n
         batch_b = self._batch_class(len(batch))
-        t_admit = self.clock.now()
-        for req in batch:
-            self._tracer.mark(req.stream_id, req.seq, "admit", t=t_admit)
+        tracer = self._tracer
+        with tracer.region("serve.launch", batch=batch_id):
+            t_admit = self.clock.now()
+            for req in batch:
+                tracer.mark(req.stream_id, req.seq, "admit", t=t_admit)
 
-        states = [req.omega_hint if req.omega_hint is not None
-                  else self._warm.get(req.stream_id,
-                                      self.workload.default_state())
-                  for req in batch]
-        # fill slots replicate the leader (finite data, results discarded)
-        data, state_batch, n_fill = self.workload.make_batch(
-            [req.window for req in batch], states, bucket_n, batch_b)
-        pre_compiles = self._m.compiles.value
-        fn = self._executable(bucket_n, batch_b)
-        compiled = self._m.compiles.value != pre_compiles
-        t_dispatch = self.clock.now()
-        for req in batch:
-            self._tracer.mark(req.stream_id, req.seq, "dispatch",
-                              t=t_dispatch, batch_b=batch_b,
-                              compile=compiled)
-        res = jax.block_until_ready(fn(data, state_batch))
+            states = [req.omega_hint if req.omega_hint is not None
+                      else self._warm.get(req.stream_id,
+                                          self.workload.default_state())
+                      for req in batch]
+            # fill slots replicate the leader (finite data, results
+            # discarded)
+            with tracer.region("serve.make_batch", batch=batch_id):
+                data, state_batch, n_fill = self.workload.make_batch(
+                    [req.window for req in batch], states, bucket_n,
+                    batch_b)
+            fn = self._executable(bucket_n, batch_b)
+            with tracer.region("serve.dispatch", batch=batch_id):
+                pre_compiles = _xla_compiles()
+                handle = fn(data, state_batch)
+                compiles = _xla_compiles() - pre_compiles
+            t_dispatch = self.clock.now()
+            for req in batch:
+                tracer.mark(req.stream_id, req.seq, "dispatch",
+                            t=t_dispatch, batch_b=batch_b, batch=batch_id,
+                            compile=compiles > 0)
+        with tracer.region("serve.wait", batch=batch_id):
+            res = jax.block_until_ready(handle)
         t_done = self.clock.now()
         self._m.execute.observe(t_done - t_dispatch)
 
-        slot = self.workload.harvest(res, False)
-        self._m.spilled_taps.inc(self.workload.spilled_taps(res, len(batch)))
-        out = []
-        for i, req in enumerate(batch):
-            out_i, state, iters, _ = slot(i)
-            if state is not None:
-                self._warm[req.stream_id] = state
-            self._tracer.finish(req.stream_id, req.seq, "harvest", "ok",
-                                iters=iters, t=t_done)
-            out.append(WindowResponse(
-                stream_id=req.stream_id, seq=req.seq, omega=out_i,
-                iters=iters, bucket_n=bucket_n, batch_b=batch_b))
+        with tracer.region("serve.harvest", batch=batch_id):
+            slot = self.workload.harvest(res, False)
+            self._m.spilled_taps.inc(self.workload.spilled_taps(res,
+                                                                len(batch)))
+            out = []
+            for i, req in enumerate(batch):
+                out_i, state, iters, _ = slot(i)
+                if state is not None:
+                    self._warm[req.stream_id] = state
+                tracer.finish(req.stream_id, req.seq, "harvest", "ok",
+                              iters=iters, t=t_done)
+                out.append(WindowResponse(
+                    stream_id=req.stream_id, seq=req.seq, omega=out_i,
+                    iters=iters, bucket_n=bucket_n, batch_b=batch_b))
+            _count_passes(self._m, self.workload, [r.iters for r in out],
+                          batch_b)
 
+        self._m.xla_compiles.inc(compiles)
         self._m.windows.inc(len(batch))
         self._m.batches.inc()
         self._m.event_slots.inc(bucket_n * batch_b)

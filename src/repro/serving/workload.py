@@ -169,6 +169,12 @@ class Workload:
         0 for a workload without such a path."""
         return 0
 
+    def engine_passes(self, iters: Tuple[int, ...]) -> Tuple[int, ...]:
+        """Engine passes per stage of one window that ran `iters` (the
+        harvested slot's count), for the service's pass counters; () for
+        a workload without engine passes."""
+        return ()
+
 
 # ---------------------------------------------------------------------------
 # CMAX: the paper's contrast-maximization pipeline as a plugin.
@@ -353,6 +359,10 @@ class CmaxWorkload(Workload):
         # megakernel taps over the per-slab capacity (StageTrace.spilled)
         return int(sum(np.asarray(tr.spilled)[:n_real].sum()
                        for tr in getattr(result, "stages", ())))
+
+    def engine_passes(self, iters):
+        # the stage's entry pass plus one per iteration (StageTrace.passes)
+        return tuple(int(it) + 1 for it in iters)
 
 
 # ---------------------------------------------------------------------------

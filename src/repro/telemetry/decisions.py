@@ -15,6 +15,10 @@ coarse-to-fine stage, what actually happened:
     gain      — the measured Eq. 7 normalized variance gain of the whole
                 stage residence (None when the workload has no per-stage
                 objective, e.g. LM decode)
+    batch     — the id of the batch that carried the window (the service
+                numbers its batches at launch; request spans and the
+                profiler's `serve.*` regions carry the same id)
+    slot      — the window's slot in that batch
     verdict   — the controller's outcome, classified by
                 `core.adaptive.residence_verdict`:
                   "run"  — the gain test saturated before any bound
@@ -31,8 +35,8 @@ from collections import Counter as _TallyCounter
 from typing import Dict, List, Optional, Tuple
 
 #: canonical keys of a serialized decision record
-DECISION_FIELDS = ("type", "stream_id", "seq", "stage", "iters", "cap",
-                   "max_iters", "gain", "verdict")
+DECISION_FIELDS = ("type", "stream_id", "seq", "batch", "slot", "stage",
+                   "iters", "cap", "max_iters", "gain", "verdict")
 
 
 class DecisionLog:
@@ -41,12 +45,14 @@ class DecisionLog:
     def __init__(self):
         self.records: List[dict] = []
 
-    def record(self, stream_id: str, seq: int, stage: int, iters: int,
+    def record(self, stream_id: str, seq: int, batch: Optional[int],
+               slot: Optional[int], stage: int, iters: int,
                cap: Optional[int], max_iters: Optional[int],
                gain: Optional[float], verdict: str) -> None:
         self.records.append({
             "type": "decision", "stream_id": stream_id, "seq": seq,
-            "stage": stage, "iters": iters, "cap": cap,
+            "batch": batch, "slot": slot, "stage": stage, "iters": iters,
+            "cap": cap,
             "max_iters": max_iters, "gain": gain, "verdict": verdict})
 
     def drain(self) -> List[dict]:

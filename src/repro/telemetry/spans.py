@@ -16,13 +16,23 @@ span's phase decomposition telescopes exactly onto the response latency:
     queue_wait (submit→admit) + assemble (admit→dispatch)
         + execute (dispatch→harvest)  ==  t_done - t_submit
 
+Besides request spans, a live tracer opens *regions* (`Tracer.region`):
+the service's own steps (`serve.poll`, `serve.launch`, `serve.make_batch`,
+`serve.dispatch`, `serve.wait`, `serve.harvest`) as
+`jax.profiler.TraceAnnotation`s, on the profiler's clock beside the
+device's operations. A region carries the batch id the service gives
+each batch at launch, as a span does (`batch`), so profiler regions,
+request spans and decision records name the same batch.
+
 The tracer is the *optional* half of the telemetry layer: the default
-service runs a `NullTracer` (every method a no-op, nothing retained), so
-tracing costs nothing unless a caller opts in (`Telemetry(spans=True)`,
-or the `--trace-out` serving flag).
+service runs a `NullTracer` (every method a no-op, nothing retained, one
+shared no-op context for every region), so tracing costs nothing unless a
+caller opts in (`Telemetry(spans=True)`, or the `--trace-out` serving
+flag).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Dict, List, Optional, Tuple
 
 #: canonical lifecycle event names, in order of occurrence
@@ -31,7 +41,7 @@ SPAN_EVENTS = ("submit", "admit", "dispatch", "harvest", "shed")
 #: canonical keys of a serialized span (the cross-workload schema pinned
 #: by tests/test_workload_conformance.py)
 SPAN_FIELDS = ("type", "stream_id", "seq", "qos", "bucket_n", "batch_b",
-               "status", "compile", "iters", "events", "phases",
+               "batch", "status", "compile", "iters", "events", "phases",
                "latency_s")
 
 
@@ -40,7 +50,7 @@ class Span:
     ordered (event, clock-time) list the phases derive from."""
 
     __slots__ = ("stream_id", "seq", "qos", "bucket_n", "batch_b",
-                 "status", "compile", "iters", "events")
+                 "batch", "status", "compile", "iters", "events")
 
     def __init__(self, stream_id: str, seq: int, qos: str, bucket_n: int,
                  t_submit: float):
@@ -49,8 +59,10 @@ class Span:
         self.qos = qos
         self.bucket_n = bucket_n
         self.batch_b = 0
+        self.batch: Optional[int] = None        # batch id, set at dispatch
         self.status: Optional[str] = None       # set at finish
-        self.compile: Optional[bool] = None     # set at dispatch
+        # whether an XLA backend compile ran inside the dispatch, set there
+        self.compile: Optional[bool] = None
         self.iters: Tuple[int, ...] = ()
         self.events: List[Tuple[str, float]] = [("submit", t_submit)]
 
@@ -90,7 +102,7 @@ class Span:
         return {"type": "span", "stream_id": self.stream_id,
                 "seq": self.seq, "qos": self.qos,
                 "bucket_n": self.bucket_n, "batch_b": self.batch_b,
-                "status": self.status, "compile": self.compile,
+                "batch": self.batch, "status": self.status, "compile": self.compile,
                 "iters": list(self.iters),
                 "events": [[n, t] for n, t in self.events],
                 "phases": self.phases(), "latency_s": self.latency_s}
@@ -119,6 +131,7 @@ class Tracer:
 
     def mark(self, stream_id: str, seq: int, event: str,
              t: Optional[float] = None, batch_b: Optional[int] = None,
+             batch: Optional[int] = None,
              compile: Optional[bool] = None) -> None:
         sp = self._open.get((stream_id, seq))
         if sp is None:
@@ -126,6 +139,8 @@ class Tracer:
         sp.events.append((event, self._now(t)))
         if batch_b is not None:
             sp.batch_b = batch_b
+        if batch is not None:
+            sp.batch = batch
         if compile is not None:
             sp.compile = compile
 
@@ -139,6 +154,12 @@ class Tracer:
         sp.status = status
         sp.iters = tuple(iters)
         self.spans.append(sp)
+
+    def region(self, name: str, **ids):
+        """A profiler span around one step of the service, with `ids`
+        (e.g. `batch=7`) as its stats."""
+        import jax
+        return jax.profiler.TraceAnnotation(name, **ids)
 
     def drain(self) -> List[Span]:
         """Hand over (and forget) the completed spans — long-running
@@ -156,6 +177,7 @@ class NullTracer:
     enabled = False
     clock = None
     spans: Tuple[Span, ...] = ()
+    _no_region = contextlib.nullcontext()
 
     def start(self, *a, **kw) -> None:
         pass
@@ -165,6 +187,9 @@ class NullTracer:
 
     def finish(self, *a, **kw) -> None:
         pass
+
+    def region(self, name: str, **ids):
+        return self._no_region
 
     def drain(self) -> tuple:
         return ()

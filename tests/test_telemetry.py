@@ -179,6 +179,9 @@ def test_fakeclock_traces_are_deterministic():
         svc.drain()
         return json.dumps(tel.trace_records(), sort_keys=True)
 
+    # a span's `compile` says whether XLA compiled inside its dispatch,
+    # which only a process's first run of these shapes does
+    run()
     assert run() == run()
 
 
@@ -261,6 +264,8 @@ def test_decision_log_reproduces_response_iters():
     assert len(tel.decisions.records) == len(rs) * n_stages
     for rec in tel.decisions.records:
         assert tuple(rec) == DECISION_FIELDS
+        assert 0 <= rec["batch"] < svc.stats["batches"]
+        assert 0 <= rec["slot"] < 2
         assert rec["verdict"] in ("run", "cap", "max", "skip")
         assert rec["cap"] is None                 # unbudgeted run
         assert rec["max_iters"] == int(svc.cfg.stages[rec["stage"]].max_iters)

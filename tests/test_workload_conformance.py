@@ -318,3 +318,7 @@ def test_span_schema_conformance(harness):
         assert d["latency_s"] == r.latency      # same clock reads
         assert sum(d["phases"].values()) == pytest.approx(r.latency,
                                                           abs=1e-12)
+    # every span names the batch that carried it: one id per batch
+    batch_ids = [s.batch for s in spans]
+    assert all(isinstance(b, int) for b in batch_ids)
+    assert len(set(batch_ids)) == svc.stats["batches"]
