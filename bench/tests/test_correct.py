@@ -47,7 +47,7 @@ def small_spec(config: str, loop: str = "closed") -> harness.CellSpec:
                                            "BENCHMARK.json"))
     want = ("windows_per_s",) if loop == "closed" else ()
     e2e = [m for m in bench["end_to_end"]
-           if m["name"] in want + ("rmse_rad_s", "setup_s")]
+           if m["name"] in want + ("rmse_fixed_rad_s", "setup_s")]
     return harness.CellSpec(f"small.{loop}", cfg, mix, e2e, [])
 
 
@@ -79,6 +79,10 @@ def test_sound_run_is_correct(loop):
     assert out["failed"] == 0 and out["attempted"] > 0
     assert set(out["metrics"]) == {m["name"] for m in
                                    small_spec("cmax240-ref", loop).end_to_end}
+    # the accuracy set is complete however few windows the window served
+    mix = small_spec("cmax240-ref", loop).mix
+    assert out["attempted"] >= \
+        mix["cameras"] * mix["accuracy_windows_per_camera"]
 
 
 def test_control_fails():

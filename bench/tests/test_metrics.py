@@ -54,3 +54,12 @@ def test_every_cell_reports_setup_and_another_end_to_end_metric():
         names = {m["name"] for m in spec.end_to_end}
         assert "setup_s" in names and len(names) >= 2
         assert spec.per_layer
+
+
+def test_every_cell_reports_accuracy_on_its_fixed_set():
+    bench = bench_file()
+    assert "rmse_rad_s" not in {m["name"] for m in bench["end_to_end"]}
+    for w in bench["workloads"]:
+        spec = harness.cell_spec(w["name"], ROOT)
+        assert "rmse_fixed_rad_s" in {m["name"] for m in spec.end_to_end}
+        assert spec.mix["accuracy_windows_per_camera"] >= 1

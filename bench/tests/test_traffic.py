@@ -7,7 +7,8 @@ from bench.traffic.generate import generate
 CAM = {"width": 64, "height": 48, "fx": 60.0, "fy": 60.0, "cx": 32.0,
        "cy": 24.0}
 MIX = {"loop": "open", "cameras": 4, "rate_per_s": 6.2,
-       "recording_windows": 24, "recordings_seed": 2017,
+       "recording_windows": 24, "accuracy_windows_per_camera": 1,
+       "recordings_seed": 2017,
        "events_per_window": 200, "window_dt": 0.02, "jerk_prob": 0.2,
        "jerk_scale": 0.5, "imu_noise": 0.03,
        "scenes": [{"name": "poster", "n_features": 20, "omega_scale": 3.5,
