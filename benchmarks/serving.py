@@ -127,11 +127,9 @@ def _reference_chain(cfg, workload, policy) -> Dict[Tuple[str, int],
     """Sequential reference: one window at a time, in stream order, warm-
     start chained, through the same jitted batch pipeline at batch 1.
     Any service variant must reproduce this bit-exactly — batching and
-    scheduling must never change results. (The unbatched
-    `estimate_window` path differs from the vmapped pipeline at float
-    rounding level, which the adaptive iteration count can amplify
-    across a warm-start chain; that vmap-vs-scalar tolerance is pinned
-    separately in tests/test_batching.py.)"""
+    scheduling must never change results. (On the engines that map over
+    slots a slot also equals the unbatched `estimate_window` bit for
+    bit, tests/test_batching.py.)"""
     ref = {}
     for sid, (ragged, _) in workload.items():
         om = np.zeros((1, 3), np.float32)

@@ -4,10 +4,12 @@ Edge deployment is single-chip, but fleet-scale workloads (dataset-wide
 motion ground-truthing, hyperparameter sweeps over tau/step schedules,
 multi-camera rigs, and the batched estimation service in launch/serve.py)
 batch thousands of independent windows — a pure data-parallel problem.
-Windows shard over the (pod, data) axes; the per-window adaptive
-while_loops vmap to masked lockstep iterations (a window that converged
-early contributes masked no-ops, the SIMT analog of the controller's clock
-gating; the energy model keeps per-window true iteration counts).
+Windows shard over the (pod, data) axes; on each device the local slots
+run one after another, each with its own adaptive while_loops (the
+batched megakernel runs them in masked lockstep instead: a window that
+converged early contributes masked no-ops, the SIMT analog of the
+controller's clock gating). The energy model keeps per-window true
+iteration counts either way.
 
 Two entry points, both free of collectives in the step (verified by
 tests/test_sharding_subprocess):
@@ -22,10 +24,6 @@ tests/test_sharding_subprocess):
     for (S, K, N) stream batches with warm-start chaining inside each
     stream (scan over K, vmap over the local S shard).
 
-`estimate_batch_distributed` is the older NamedSharding+jit spelling of
-the batch path (the compiler infers the same zero-collective program); it
-is kept because it accepts batch sizes that do not divide the mesh.
-
 Sharded results come back with the same leading axis layout they went in
 with, so callers index them exactly like the single-device results of
 `core.pipeline.estimate_batch` / `estimate_streams`.
@@ -35,7 +33,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from .pipeline import (WindowResult, estimate_streams,
                        estimate_windows_parallel)
@@ -51,18 +49,6 @@ def _dp_extent(mesh) -> int:
     for a in _dp_axes(mesh):
         n *= mesh.shape[a]
     return n
-
-
-def shard_windows(windows: EventWindow, omega0s: jax.Array, mesh
-                  ) -> Tuple[EventWindow, jax.Array]:
-    """Place a (K, N) window batch sharded over the DP axes."""
-    dp = _dp_axes(mesh)
-    s2 = NamedSharding(mesh, P(dp, None))
-    windows = EventWindow(*(jax.device_put(a, s2)
-                            for a in (windows.x, windows.y, windows.t,
-                                      windows.p, windows.valid)))
-    omega0s = jax.device_put(omega0s, s2)
-    return windows, omega0s
 
 
 def _leading_axis_specs(fn, dp, *abstract_args):
@@ -125,11 +111,3 @@ def estimate_streams_sharded(windows: EventWindow, omega_inits: jax.Array,
                      cfg, mesh, dp, windows, omega_inits)
     return fn(windows, omega_inits)
 
-
-def estimate_batch_distributed(windows: EventWindow, omega0s: jax.Array,
-                               cfg: CmaxConfig, mesh) -> WindowResult:
-    """jit + vmap over DP-sharded windows. Independent windows => zero
-    collectives in the step (verified by tests/test_sharding_subprocess)."""
-    windows, omega0s = shard_windows(windows, omega0s, mesh)
-    fn = jax.jit(lambda w, o: estimate_windows_parallel(w, o, cfg))
-    return fn(windows, omega0s)

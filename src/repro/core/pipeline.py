@@ -342,6 +342,15 @@ def _estimate_batch_lockstep(windows: EventWindow, omega0s: jax.Array,
     return WindowResult(omega=omega, stages=tuple(traces))
 
 
+def _map_slots(fn, windows: EventWindow, *per_slot) -> WindowResult:
+    """Batch entry for the engines that do not run in lockstep: `lax.map`
+    runs fn(window, *slot_args) on each slot in turn, so every window
+    keeps its own stage `while_loop`s and B = 1 engine passes, and no slot
+    waits on another's residence. `per_slot` are (B, ...) operands mapped
+    beside the windows (warm starts, iteration caps)."""
+    return jax.lax.map(lambda a: fn(*a), (windows,) + per_slot)
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def estimate_window(ev: EventWindow, omega0: jax.Array,
                     cfg: CmaxConfig) -> WindowResult:
@@ -413,10 +422,8 @@ def estimate_batch_budgeted(windows: EventWindow, omega0s: jax.Array,
     if cfg.engine == "pallas_batched":
         return _estimate_batch_lockstep(windows, omega0s, cfg,
                                         iter_caps=iter_caps)
-    return jax.vmap(lambda x, y, t, p, v, o, c: estimate_window_budgeted(
-        EventWindow(x, y, t, p, v), o, c, cfg))(
-        windows.x, windows.y, windows.t, windows.p, windows.valid,
-        omega0s, iter_caps)
+    return _map_slots(lambda ev, o, c: estimate_window_budgeted(
+        ev, o, c, cfg), windows, omega0s, iter_caps)
 
 
 def estimate_sequence(windows: EventWindow, omega_init: jax.Array,
@@ -442,13 +449,13 @@ def estimate_windows_parallel(windows: EventWindow, omega0s: jax.Array,
     the building block for data-parallel multi-device CMAX (distributed.py).
 
     Under engine="pallas_batched" the whole batch runs in masked lockstep
-    with one megakernel launch per engine pass; otherwise each window's
-    pipeline is vmapped independently."""
+    with one megakernel launch per engine pass; on the other engines each
+    slot runs `estimate_window` on its own, one after another
+    (`_map_slots`)."""
     if cfg.engine == "pallas_batched":
         return _estimate_batch_lockstep(windows, omega0s, cfg)
-    return jax.vmap(lambda x, y, t, p, v, o: estimate_window(
-        EventWindow(x, y, t, p, v), o, cfg))(
-        windows.x, windows.y, windows.t, windows.p, windows.valid, omega0s)
+    return _map_slots(lambda ev, o: estimate_window(ev, o, cfg),
+                      windows, omega0s)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -460,11 +467,14 @@ def estimate_batch(windows: EventWindow, omega0s: jax.Array,
     valid=False; `omega0s` is (B, 3) of per-window warm starts. One compiled
     executable exists per (B, N, cfg) triple — the serving layer
     (launch/serve.py) bounds that set by bucketing N and B into length
-    classes (DESIGN.md §4). The per-window adaptive while_loops run in
-    masked lockstep under vmap: a window that saturates early contributes
-    masked no-ops until the slowest window in the batch finishes (the SIMT
-    analog of the controller's clock gating; per-window true iteration
-    counts survive in the returned traces).
+    classes (DESIGN.md §4). On the "reference" and "pallas" engines each
+    slot runs its own adaptive while_loops (`lax.map` over slots): a
+    window that saturates early ends its residence and the next slot
+    starts. Only "pallas_batched", whose engine pass covers the whole
+    batch, runs the windows in masked lockstep, finished windows
+    contributing masked no-ops until the slowest is done (the SIMT analog
+    of the controller's clock gating). Per-window true iteration counts
+    survive in the returned traces on every engine.
     """
     return estimate_windows_parallel(windows, omega0s, cfg)
 
@@ -483,11 +493,13 @@ def estimate_batch_donated(windows: EventWindow, omega0s: jax.Array,
     are futures; callers poll readiness (`jax.Array.is_ready`) or block.
 
     Per-slot results depend only on that slot's window and warm start —
-    vmap lowers each window's computation independently — so a stream's
-    warm-start chain is preserved bit-for-bit no matter which in-flight
-    batch, slot position, or fill pattern its windows land in. That
-    invariant is what lets the service refill finished slots out of order
-    (tests/test_serving_async.py pins it).
+    by construction on the non-lockstep engines, where each slot runs
+    `estimate_window` alone, and through the carry-select masking of the
+    lockstep megakernel path — so a stream's warm-start chain is preserved
+    bit-for-bit no matter which in-flight batch, slot position, or fill
+    pattern its windows land in. That invariant is what lets the service
+    refill finished slots out of order (tests/test_serving_async.py pins
+    it).
     """
     return estimate_windows_parallel(windows, omega0s, cfg)
 

@@ -93,7 +93,7 @@ class StageConfig:
 #:   "reference"      — the pure-jnp scatter + blur_separable datapath (the
 #:                      correctness oracle; XLA fuses it reasonably on CPU)
 #:   "pallas"         — per-window fused Pallas kernels (iwe_accum +
-#:                      blur_stats); batching is vmap over windows
+#:                      blur_stats); a batch maps over its windows
 #:   "pallas_batched" — the batched megakernel: one (batch, slab)-grid
 #:                      pallas_call per engine pass for the WHOLE batch
 #:                      (kernels/megakernel.py); the hot loop runs windows

@@ -326,8 +326,10 @@ class _ServingMetrics:
         self.xla_compiles = c("repro_serving_xla_compiles_total",
                               "XLA backend compiles inside a dispatch")
         self.slot_passes = c("repro_serving_slot_passes_total",
-                             "engine passes the device ran: batch slots x "
-                             "the slowest real window's passes, per stage")
+                             "engine passes the device ran: in lockstep, "
+                             "batch slots x the slowest real window's "
+                             "passes per stage; else each slot's own, a "
+                             "fill slot running the leader's")
         self.window_passes = c("repro_serving_window_passes_total",
                                "engine passes the served windows needed")
         self.event_slots = c("repro_serving_event_slots_total",
@@ -406,16 +408,26 @@ class _StatsView(MutableMapping):
         return repr(dict(self))
 
 
-def _count_passes(m: _ServingMetrics, workload, iters, batch_b: int
-                  ) -> None:
-    """Engine passes of one harvested batch. The batch runs in lockstep:
-    every slot, fill slots included, runs as many passes per stage as the
-    batch's slowest real window, while each window needed only its own."""
+def _count_passes(m: _ServingMetrics, workload, iters, batch_b: int,
+                  caps: Optional[np.ndarray] = None) -> None:
+    """Engine passes of one harvested batch; each window needed its own.
+    In lockstep every slot, fill slots included, runs as many passes per
+    stage as the batch's slowest real window. Otherwise each slot runs its
+    own: a fill slot replicates the leader, so it runs the leader's
+    iterations, cut to its budget cap where the batch has `caps`."""
     per_window = [workload.engine_passes(it) for it in iters]
     if not per_window or not per_window[0]:
         return
     p = np.asarray(per_window, np.int64)            # (windows, stages)
-    m.slot_passes.inc(batch_b * int(p.max(axis=0).sum()))
+    if workload.lockstep:
+        slot = batch_b * int(p.max(axis=0).sum())
+    else:
+        leader = np.asarray(iters[0])
+        fills = [leader if caps is None else np.minimum(leader, caps[j])
+                 for j in range(len(iters), batch_b)]
+        slot = int(p.sum()) + sum(sum(workload.engine_passes(it))
+                                  for it in fills)
+    m.slot_passes.inc(slot)
     m.window_passes.inc(int(p.sum()))
 
 
@@ -743,7 +755,8 @@ class AsyncBatchedEstimationService:
                 fb.bucket_n, fb.batch_b, status="ok",
                 t_submit=r.t_submit, t_done=now, qos=r.qos))
         self._m.windows.inc(len(fb.requests))
-        _count_passes(self._m, self.workload, all_iters, fb.batch_b)
+        _count_passes(self._m, self.workload, all_iters, fb.batch_b,
+                      fb.caps)
 
     def _record_decisions(self, r: WindowRequest, iters: Tuple[int, ...],
                           caps: Optional[np.ndarray], batch: int, i: int,

@@ -175,6 +175,14 @@ class Workload:
         a workload without engine passes."""
         return ()
 
+    @property
+    def lockstep(self) -> bool:
+        """Whether the executable runs a batch's slots in lockstep, every
+        slot passing as often per stage as the slowest window; otherwise
+        each slot runs only its own passes. Read by the service's pass
+        counters."""
+        return True
+
 
 # ---------------------------------------------------------------------------
 # CMAX: the paper's contrast-maximization pipeline as a plugin.
@@ -363,6 +371,12 @@ class CmaxWorkload(Workload):
     def engine_passes(self, iters):
         # the stage's entry pass plus one per iteration (StageTrace.passes)
         return tuple(int(it) + 1 for it in iters)
+
+    @property
+    def lockstep(self) -> bool:
+        # only the megakernel's pass covers the whole batch
+        # (core/pipeline.py: the other engines map over slots)
+        return self.cfg.engine == "pallas_batched"
 
 
 # ---------------------------------------------------------------------------

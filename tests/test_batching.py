@@ -1,14 +1,19 @@
 """Ragged-window batching layer + batched estimation service
 (DESIGN.md §4): bucketing preserves every event, padded slots are inert,
 and the batched/serving paths reproduce per-window estimation."""
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from helpers import small_camera
+from helpers import random_window, small_camera
 
 from repro.core import (CmaxConfig, StageConfig, estimate_batch,
                         estimate_sequence, estimate_streams, estimate_window)
+from repro.core.pipeline import (estimate_batch_budgeted,
+                                 estimate_window_budgeted)
 from repro.core.types import EventWindow
 from repro.data import events as ev_data
 from repro.launch.serve import BatchedEstimationService
@@ -145,6 +150,64 @@ def test_estimate_batch_matches_per_window():
                                    np.asarray(ref.omega), atol=1e-5)
         for tr_b, tr_1 in zip(res.stages, ref.stages):
             assert int(tr_b.iters[i]) == int(tr_1.iters)
+
+
+def _assert_slot_equals(res, i, ref):
+    """Every leaf of slot i of a batched result equals the lone window's
+    result bit for bit (NaN padding of `v_history` included)."""
+    for got, want in zip(jax.tree.leaves(res), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
+@pytest.mark.parametrize("entry,engine,b", [
+    ("batch", "reference", 1), ("batch", "reference", 2),
+    ("batch", "reference", 4), ("budgeted", "reference", 2),
+    ("batch", "pallas", 2)])
+def test_batch_slot_is_the_lone_window_bitwise(entry, engine, b):
+    """On the engines that map over slots, slot i of a batch of any size
+    runs the unbatched `estimate_window` program on window i alone, so it
+    equals that call bit for bit, every trace leaf included: bitwise, not
+    allclose, because no batch axis reaches the scatter, the sort or the
+    CG-PR update."""
+    cam = small_camera()
+    cfg = dataclasses.replace(fast_cfg(cam), engine=engine)
+    wins = [random_window(256, cam=cam, seed=80 + i) for i in range(b)]
+    om0 = jnp.array([[0.1, -0.05, 0.2], [0.0, 0.3, -0.1],
+                     [0.2, 0.0, 0.0], [-0.1, 0.1, 0.1]], jnp.float32)[:b]
+    batch = ev_data.batch_windows(wins, 256)
+    if entry == "batch":
+        res = estimate_batch(batch, om0, cfg)
+        refs = [estimate_window(w, om0[i], cfg) for i, w in enumerate(wins)]
+    else:
+        caps = jnp.array([[1, 4], [3, 2]], jnp.int32)[:b]
+        res = estimate_batch_budgeted(batch, jnp.array(om0), caps, cfg)
+        refs = [estimate_window_budgeted(w, om0[i], caps[i], cfg)
+                for i, w in enumerate(wins)]
+    for i, ref in enumerate(refs):
+        _assert_slot_equals(res, i, ref)
+
+
+@pytest.mark.parametrize("fill", ["leader", "others", "empty"])
+def test_part_full_batch_real_slots_unchanged_by_fill(fill):
+    """Two real windows in a batch of 4: whatever fills slots 2 and 3 (the
+    service's leader replicas, other windows, or windows with no valid
+    event), the real slots equal the two windows served alone."""
+    cam = small_camera()
+    cfg = fast_cfg(cam)
+    real = [random_window(256, cam=cam, seed=90 + i) for i in range(2)]
+    if fill == "leader":
+        batch, n_fill = ev_data.fill_batch(real, 256, 4)
+        assert n_fill == 2
+    else:
+        extra = [random_window(256, cam=cam, seed=95 + i,
+                               valid_frac=0.0 if fill == "empty" else 1.0)
+                 for i in range(2)]
+        batch = ev_data.batch_windows(real + extra, 256)
+    om0 = jnp.array([[0.1, -0.05, 0.2], [0.0, 0.3, -0.1],
+                     [0.1, -0.05, 0.2], [0.1, -0.05, 0.2]], jnp.float32)
+    res = estimate_batch(batch, om0, cfg)
+    for i, w in enumerate(real):
+        _assert_slot_equals(res, i, estimate_window(w, om0[i], cfg))
 
 
 def test_estimate_streams_matches_sequence():

@@ -2,6 +2,7 @@
 `serve.*` regions with their batch ids, the `cmax.*` device scopes of the
 compiled pipeline, the engine-pass counters, and the `compile` flag that
 counts XLA compiles inside a dispatch."""
+import dataclasses
 import glob
 import re
 
@@ -15,7 +16,8 @@ from helpers import random_window, small_camera
 from repro.core import CmaxConfig, EventWindow, StageConfig
 from repro.core.pipeline import estimate_batch
 from repro.data import events as ev_data
-from repro.launch.serve import (BatchedEstimationService, FakeClock,
+from repro.launch.serve import (AsyncBatchedEstimationService,
+                                BatchedEstimationService, FakeClock,
                                 InlineExecutor, _count_passes,
                                 _ServingMetrics)
 from repro.serving.workload import CmaxWorkload
@@ -94,32 +96,65 @@ def test_profiler_regions_carry_the_batch_ids_of_spans_and_decisions(
         assert sorted(s for _, s in got) == list(range(len(members)))
 
 
-def test_pass_counters_on_a_hand_computed_batch():
-    """Two real windows in a batch of 4: every slot runs the slowest
-    window's passes per stage (iters + 1), the windows needed their own."""
+@pytest.mark.parametrize("engine,caps,slot_passes", [
+    # each window its own passes (8 + 8), each fill slot the leader's (8)
+    ("reference", None, 16 + 2 * 8),
+    # budgeted fill slots are capped at 1 iteration: the leader's
+    # (2, 3, 0) cut to (1, 1, 0), so 2 + 2 + 1 passes each
+    ("reference", [[9, 9, 9], [9, 9, 9], [1, 1, 1], [1, 1, 1]],
+     16 + 2 * 5),
+    # lockstep: every slot the slowest per stage, 5 + 4 + 1 -> 4 slots x 10
+    ("pallas_batched", None, 40)])
+def test_pass_counters_on_a_hand_computed_batch(engine, caps, slot_passes):
+    """Two real windows in a batch of 4; the windows needed their own
+    passes per stage (iters + 1). What the device ran depends on whether
+    the engine runs the batch in lockstep."""
     m = _ServingMetrics(MetricsRegistry())
-    wl = CmaxWorkload(fast_cfg())
-    _count_passes(m, wl, [(2, 3, 0), (4, 1, 0)], batch_b=4)
-    # slowest per stage: 5, 4, 1 passes -> 4 slots x 10
-    assert m.slot_passes.value == 40
+    wl = CmaxWorkload(dataclasses.replace(fast_cfg(), engine=engine))
+    assert wl.lockstep == (engine == "pallas_batched")
+    _count_passes(m, wl, [(2, 3, 0), (4, 1, 0)], batch_b=4,
+                  caps=None if caps is None else np.asarray(caps))
+    assert m.slot_passes.value == slot_passes
     assert m.window_passes.value == (3 + 4 + 1) + (5 + 2 + 1)
 
 
-def test_pass_counters_of_a_served_run():
+@pytest.mark.parametrize("engine", ["reference", "pallas_batched"])
+def test_pass_counters_of_a_served_run(engine):
+    """A batch of 3 windows in class 4 and one of 2 in class 2. On the
+    reference engine the device ran each window's passes plus the
+    leader's for the fill slot; on the megakernel every slot ran the
+    batch's slowest window's passes per stage."""
     cam = small_camera()
-    svc = make_svc(cam, executor=InlineExecutor(), max_batch=2)
+    tel = Telemetry(decisions=True)
+    svc = AsyncBatchedEstimationService(
+        dataclasses.replace(fast_cfg(cam), engine=engine),
+        policy=ev_data.pow2_policy(min_bucket=128, max_bucket=512),
+        clock=FakeClock(), executor=InlineExecutor(), max_batch=4,
+        telemetry=tel)
     for k in range(2):
         svc.submit("a", one_window(cam, seed=k))
         svc.submit("b", one_window(cam, seed=10 + k))
+    svc.submit("c", one_window(cam, seed=20))
     rs = svc.drain()
-    snap = svc.telemetry.registry.snapshot()
+    snap = tel.registry.snapshot()
     assert snap["repro_serving_window_passes_total"] == sum(
         sum(it + 1 for it in r.iters) for r in rs)
-    by_batch = {}
-    for r in rs:    # both streams' windows k share a batch of class 2
-        by_batch.setdefault(r.seq, []).append(r.iters)
-    want = sum(2 * sum(max(its) + 1 for its in zip(*group))
-               for group in by_batch.values())
+    batch_b = {(r.stream_id, r.seq): r.batch_b for r in rs}
+    passes = {}     # batch -> {slot: passes per stage}
+    width = {}      # batch -> batch class
+    for d in tel.decisions.records:
+        slots = passes.setdefault(d["batch"], {})
+        slots.setdefault(d["slot"], []).append(d["iters"] + 1)
+        width[d["batch"]] = batch_b[(d["stream_id"], d["seq"])]
+    assert sorted((len(s), width[b]) for b, s in passes.items()) == \
+        [(2, 2), (3, 4)]
+    want = 0
+    for b, slots in passes.items():
+        p = np.asarray([slots[i] for i in range(len(slots))])
+        if engine == "pallas_batched":
+            want += width[b] * int(p.max(axis=0).sum())
+        else:
+            want += int(p.sum()) + (width[b] - len(p)) * int(p[0].sum())
     assert snap["repro_serving_slot_passes_total"] == want
     assert want >= snap["repro_serving_window_passes_total"]
 
